@@ -665,4 +665,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro import tune
+
+    tune.enable_compilation_cache()
     sys.exit(main())

@@ -259,7 +259,7 @@ def bench_pipeline_stages():
     # (parallel workers bill CPU-seconds) and the table build must stay in
     # the "extract" stage (the plan cache would move it to plan_build_s)
     stage = {}
-    r, t_e2e = timed(engine_jax.count, g, k, interpret=True,
+    r, t_e2e = timed(engine_jax.count, g, k,
                      pack_workers=0, plan_cache=False,
                      stage_times=stage)
     breakdown = ";".join(
@@ -949,8 +949,7 @@ def bench_device_engine():
     for T, packed in binned.items():
         A, cand = jnp.asarray(packed.A), jnp.asarray(packed.cand)
         (hard, nv, t, f), dt = timed(
-            engine_jax.count_packed, A, cand, k - 2, et=True,
-            interpret=True)
+            engine_jax.count_packed, A, cand, k - 2, et=True)
         total += engine_jax.combine_counts(hard, nv, t, f, k - 2, True)
         n_tiles += packed.A.shape[0]
         flops_mxu += packed.A.shape[0] * 2 * T ** 3  # dense-tile matmul path
@@ -1091,4 +1090,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro import tune
+
+    tune.enable_compilation_cache()
     main()

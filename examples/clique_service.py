@@ -149,4 +149,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import tune
+
+    tune.enable_compilation_cache()
     main()

@@ -224,6 +224,7 @@ def count(g: Graph, k: int, order: str = "hybrid", et_t: int = 3,
           bins: Optional[Sequence[int]] = None,
           stage_times: Optional[Dict[str, float]] = None,
           devices=None, async_staging: bool = True,
+          mesh: Optional[jax.sharding.Mesh] = None,
           backend: Optional[str] = None,
           pack_workers: Optional[int] = None,
           prefetch: Optional[int] = None,
@@ -254,9 +255,10 @@ def count(g: Graph, k: int, order: str = "hybrid", et_t: int = 3,
     dispatcher (:mod:`repro.runtime.dispatch`): an int n / ``"all"`` / a
     device list shards batches across those devices with per-device jit
     and double-buffered host->device staging (``async_staging=False``
-    forces synchronous staging).  ``devices=None`` keeps the single-device
-    inline path.  Counts are identical either way -- device partials are
-    combined exactly on the host.
+    forces synchronous staging).  ``mesh`` instead shards every batch
+    over that mesh in one ``shard_map`` step per batch.  With neither,
+    the single-device inline path runs.  Counts are identical every way --
+    device partials are combined exactly on the host.
 
     Geometry knobs left ``None`` (``batch_size``, ``bins``,
     ``pack_workers``, ``prefetch``) resolve through the persistent
@@ -289,9 +291,9 @@ def count(g: Graph, k: int, order: str = "hybrid", et_t: int = 3,
                                      timings=stage_times,
                                      pack_workers=geom.pack_workers,
                                      prefetch=geom.prefetch, stats=stats)
-    if devices is not None:
+    if devices is not None or mesh is not None:
         from ..runtime.dispatch import Dispatcher
-        disp = Dispatcher(l, devices, et=et, method=method,
+        disp = Dispatcher(l, devices, mesh=mesh, et=et, method=method,
                           interpret=interpret, backend=backend,
                           async_staging=async_staging,
                           stats=stats, stage_times=stage_times)

@@ -12,9 +12,12 @@ served by one of several interchangeable backends:
   native XLA:CPU/GPU code.  Byte-identical outputs, no interpreter.
 * ``"ref"`` -- the pure-jnp expansion oracles (:mod:`repro.kernels.ref`,
   counting only; memory O(B * T^(l-2)), tests/cross-checks).
-* ``"auto"`` (default) -- Mosaic Pallas on TPU, lax everywhere else.
+* ``"auto"`` (default) -- lax on every platform.  On a TPU the Mosaic
+  lowering refuses the Pallas kernels (see :func:`compiling_backends`),
+  and off a TPU Pallas only runs in interpret mode.
 * ``"autotune"`` -- per-(device kind, mode, l, T, capacity bucket)
-  microbenchmark between the pallas and lax backends.  Winners are cached
+  microbenchmark between the backends that compile for the device
+  (:func:`compiling_backends`).  Winners are cached
   in-process and, when a tune-cache directory is configured
   (:mod:`repro.tune.cache`), persisted across processes as
   :class:`~repro.tune.records.TuningRecord` files -- a warm process never
@@ -73,12 +76,29 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
     return interpret
 
 
+def compiling_backends() -> Tuple[str, ...]:
+    """Kernel backends that compile for the default device, best first.
+
+    On a TPU the Mosaic lowering refuses every Pallas kernel: the
+    ``(1, W)`` candidate blocks and rank-1 output blocks break the
+    (8, 128) block tiling, popcount sums reduce over unsigned integers,
+    and the explicit DFS stack is written by scatter.  Until the kernels
+    are relaid out, ``lax`` is the only backend there.  Elsewhere Pallas
+    runs in interpret mode, which compiles but is slow.
+    """
+    if jax.default_backend() == "tpu":
+        return ("lax",)
+    return ("lax", "pallas")
+
+
 def resolve_backend(backend: Optional[str] = None,
                     interpret: Optional[bool] = None) -> str:
     """Resolve the backend knob to a registry name (see module docstring).
 
-    ``"auto"`` resolves to a concrete backend; ``"autotune"`` is returned
-    as-is (the per-shape winner is only known once l and T are).
+    ``"auto"`` resolves to the first of :func:`compiling_backends`;
+    ``"autotune"`` is returned as-is (the per-shape winner is only known
+    once l and T are).  An explicit ``"pallas"`` is returned on every
+    platform: on a TPU its launch then raises the lowering's own error.
     """
     for cand in (backend, os.environ.get(BACKEND_ENV) or None):
         if cand is None:
@@ -92,7 +112,7 @@ def resolve_backend(backend: Optional[str] = None,
     else:
         if interpret is not None:
             return "pallas"  # deprecated alias: pin the Pallas kernels
-    return "pallas" if jax.default_backend() == "tpu" else "lax"
+    return compiling_backends()[0]
 
 
 def autotune_backend(mode: str, l: int, T: int,
@@ -109,7 +129,7 @@ def autotune_backend(mode: str, l: int, T: int,
        ``(device_kind, mode, l, T, capacity bucket)``;
     3. a persisted :class:`~repro.tune.records.TuningRecord` from the
        configured tune-cache directory (cross-process warm start);
-    4. the live lax-vs-pallas microbenchmark
+    4. the live microbenchmark over :func:`compiling_backends`
        (:func:`repro.tune.search.microbench_backend`), whose winner is
        written back through layers 2-3.
 
@@ -134,7 +154,7 @@ def autotune_backend(mode: str, l: int, T: int,
     rkey = tune.backend_key(mode, l, T,
                             capacity if mode == "list" else None)
     rec = tune.get(rkey)
-    if rec is not None and rec.data.get("winner") in ("lax", "pallas"):
+    if rec is not None and rec.data.get("winner") in compiling_backends():
         best = rec.data["winner"]
         _AUTOTUNE_CACHE[key] = best
         tune.note_event(lookup=True)

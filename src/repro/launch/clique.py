@@ -27,8 +27,7 @@ from ..obs import metrics as obs_metrics
 from ..obs import trace
 from ..obs.export import MetricsServer
 from ..obs.logging import LEVELS, get_logger, setup_logging
-from ..runtime.dispatch import (Dispatcher, dispatch_scheduled,
-                                resolve_devices)
+from ..runtime.dispatch import dispatch_scheduled, resolve_devices
 from .. import tune
 from ..tune import search as tune_search
 
@@ -78,8 +77,9 @@ def main():
     ap.add_argument("--backend", default=None,
                     choices=["auto", "pallas", "lax", "ref", "autotune"],
                     help="kernel backend (repro.kernels.ops registry); "
-                         "default auto = compiled lax off-TPU, Mosaic "
-                         "Pallas on TPU; also settable via REPRO_BACKEND")
+                         "default auto = compiled lax on every platform "
+                         "(Mosaic refuses the Pallas kernels on TPU); "
+                         "also settable via REPRO_BACKEND")
     ap.add_argument("--shard-map", action="store_true",
                     help="shard each batch over a device mesh instead of "
                          "LPT-placing whole batches on devices")
@@ -109,10 +109,9 @@ def main():
                          "O(delta m) decomposition entirely")
     ap.add_argument("--tune-cache", default=None, metavar="DIR",
                     help="persistent autotuner directory (repro.tune): "
-                         "backend/geometry tuning records plus JAX's "
-                         "persistent compilation cache, so a repeated "
-                         "invocation skips microbenchmarks AND XLA "
-                         "compiles; also settable via REPRO_TUNE_CACHE")
+                         "backend/geometry tuning records, so a repeated "
+                         "invocation skips microbenchmarks; also settable "
+                         "via REPRO_TUNE_CACHE")
     ap.add_argument("--fault-plan", default=None, metavar="SPEC",
                     help="chaos mode: seeded fault-injection plan for "
                          "repro.resilience (e.g. 'seed=7;*=0.1;"
@@ -194,33 +193,37 @@ def main():
               f"pack_workers={st.pack_workers} "
               f"frontend={st.frontend_s:.2f}s "
               f"queue_occ={st.pack_queue_occupancy:.2f}")
+        match = True
         if args.verify:
             ref = ebbkc.count(g, args.k, order=args.order, plan=plan).count
             want = ref if args.max_out is None else min(args.max_out, ref)
-            print(f"host count: {ref}  match={want == st.emitted_cliques}")
+            match = want == st.emitted_cliques
+            print(f"host count: {ref}  match={match}")
         _finish_obs(args, st, metrics_server)
+        if not match:
+            raise SystemExit("verify failed: listed count != host count")
         return
 
-    stats = Stats()
-    stage = {}
-    geom = tune_search.resolve_geometry("count", l,
-                                        batch_size=args.batch_size,
-                                        pack_workers=args.pack_workers)
-    stream = pipeline.stream_batches(plan, args.k, order=args.order,
-                                     batch_size=geom.batch_size,
-                                     bins=geom.bins,
-                                     timings=stage,
-                                     pack_workers=geom.pack_workers,
-                                     prefetch=geom.prefetch,
-                                     stats=stats)
     t0 = time.time()
+    stage = {}
     info = {}
-    n_batches = 0
-    n_tiles = 0
+    batch_txt = ""
     if args.offline_lpt:
         # materialize, then scheduler bins become real devices
+        stats = Stats()
+        geom = tune_search.resolve_geometry("count", l,
+                                            batch_size=args.batch_size,
+                                            pack_workers=args.pack_workers)
+        stream = pipeline.stream_batches(plan, args.k, order=args.order,
+                                         batch_size=geom.batch_size,
+                                         bins=geom.bins,
+                                         timings=stage,
+                                         pack_workers=geom.pack_workers,
+                                         prefetch=geom.prefetch,
+                                         stats=stats)
         batches = []
         total = 0
+        n_tiles = 0
         for item in stream:
             if isinstance(item, tiles_mod.Tile):
                 n_tiles += 1
@@ -229,33 +232,27 @@ def main():
             else:
                 batches.append(item)
                 n_tiles += item.B
-        n_batches = len(batches)
         got, info = dispatch_scheduled(
             batches, l, devices, mesh=mesh, backend=args.backend,
             async_staging=not args.sync_staging, stats=stats)
         total += got
+        batch_txt = f"batches={len(batches)} "
     else:
         # streaming: pack(i+1) on the host overlaps kernel(i) on devices
-        disp = Dispatcher(l, devices, mesh=mesh, backend=args.backend,
-                          async_staging=not args.sync_staging, stats=stats)
-        total = 0
-        for item in stream:
-            if isinstance(item, tiles_mod.Tile):
-                n_tiles += 1
-                total += engine_jax.count_spilled(item, args.order, l, stats,
-                                                  et_t=3, use_rule2=True)
-            else:
-                n_batches += 1
-                n_tiles += item.B
-                disp.submit(item)
-        total += disp.finish()
+        res = engine_jax.count(g, args.k, order=args.order, plan=plan,
+                               batch_size=args.batch_size,
+                               stage_times=stage, devices=devices, mesh=mesh,
+                               async_staging=not args.sync_staging,
+                               backend=args.backend,
+                               pack_workers=args.pack_workers)
+        total, n_tiles, stats = res.count, res.tiles, res.stats
     t_count = time.time() - t0
     # packing is interleaved with counting; stream_batches bills it apart
     t_pack = stage.get("extract", 0.0) + stage.get("pack", 0.0)
 
     balance = info.get("max_over_mean")
     bal_txt = f" balance max/mean={balance:.3f}" if balance else ""
-    print(f"batches={n_batches} tiles={n_tiles} "
+    print(f"{batch_txt}tiles={n_tiles} "
           f"spilled={stats.spilled_tiles} devices={n_dev}"
           f"{' (shard_map)' if mesh is not None else ''}{bal_txt}")
     per_dev = " ".join(
@@ -270,11 +267,16 @@ def main():
           f"of which extract+pack {t_pack:.2f}s; "
           f"pack_workers={stats.pack_workers} "
           f"queue_occ={stats.pack_queue_occupancy:.2f})")
+    match = True
     if args.verify:
         ref = ebbkc.count(g, args.k, order=args.order, plan=plan).count
-        print(f"host engine: {ref}  match={ref == total}")
+        match = ref == total
+        print(f"host engine: {ref}  match={match}")
     _finish_obs(args, stats, metrics_server)
+    if not match:
+        raise SystemExit("verify failed: device count != host count")
 
 
 if __name__ == "__main__":
+    tune.enable_compilation_cache()
     main()

@@ -15,7 +15,17 @@ import dataclasses
 import time
 from typing import Callable, Optional, Tuple, Type
 
+import jax
+
 from . import inject
+
+#: What a device launch or harvest may retry and demote on: injected
+#: faults and failures the JAX runtime reports while running a program.
+#: Trace, lowering and compile errors (``ValueError``,
+#: ``NotImplementedError``, ``TypeError``, ...) are faults of the program,
+#: not of the device, and propagate to the caller.
+RECOVERABLE: Tuple[Type[BaseException], ...] = (
+    inject.FaultInjected, jax.errors.JaxRuntimeError)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,7 +74,7 @@ def call(
     fn: Callable,
     *,
     policy: RetryPolicy = DEFAULT_POLICY,
-    retry_on: Tuple[Type[BaseException], ...] = (inject.FaultInjected,),
+    retry_on: Tuple[Type[BaseException], ...] = RECOVERABLE,
     token: str = "",
     on_retry: Optional[Callable[[int, BaseException], None]] = None,
 ):

@@ -51,14 +51,6 @@ from ..resilience import inject
 from ..resilience import retry as fault_retry
 from .clique_scheduler import schedule_batches, tile_costs
 
-if hasattr(jax, "shard_map"):  # newer jax
-    _shard_map = jax.shard_map
-    _SHARD_MAP_CHECK = {"check_vma": False}
-else:  # the pinned jax 0.4.37
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_CHECK = {"check_rep": False}
-
 
 def resolve_devices(
     devices: Union[None, int, str, Sequence] = None,
@@ -178,12 +170,12 @@ def make_sharded_step(
             backend=backend,
         )
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(axes, None, None), P(axes, None)),
         out_specs=(P(axes), P(axes), P(axes), P(axes)),
-        **_SHARD_MAP_CHECK,
+        check_vma=False,
     )
     return jax.jit(fn), axes
 
@@ -418,12 +410,12 @@ class Dispatcher:
             try:
                 out = fault_retry.call(
                     lambda b=backend: self._launch_on(batch, d, b),
-                    policy=self.retry_policy, retry_on=(Exception,),
+                    policy=self.retry_policy,
                     token="count.launch", on_retry=self._note_retry)
                 if block:
                     jax.block_until_ready(out)
                 return out
-            except Exception as exc:
+            except fault_retry.RECOVERABLE as exc:
                 self.stats.demotions += 1
                 nxt = fault_retry.demote(
                     "count", backend if backend is not None else self._backend)
@@ -498,9 +490,9 @@ class Dispatcher:
                 try:
                     padded, out = fault_retry.call(
                         launch_mesh, policy=self.retry_policy,
-                        retry_on=(Exception,), token="count.mesh",
+                        token="count.mesh",
                         on_retry=self._note_retry)
-                except Exception:
+                except fault_retry.RECOVERABLE:
                     # the SPMD step has no per-device ladder; fall straight
                     # back to the (exact) host recursion
                     self.stats.demotions += 1
@@ -560,7 +552,7 @@ class Dispatcher:
             fault_retry.consume("device.harvest", on_retry=self._note_retry)
             try:
                 jax.block_until_ready(p.out)
-            except Exception as exc:
+            except fault_retry.RECOVERABLE as exc:
                 self._note_retry(1, exc)
                 p.out = self._launch(p.batch, max(p.device, 0), block=True)
                 B = int(p.out[0].shape[0])
@@ -638,12 +630,8 @@ SPECULATIVE_CAP0 = 64
 
 def _is_ready(x) -> bool:
     """Non-blocking readiness probe of a device array (True = safe to
-    fetch without stalling).  Conservatively True when the runtime lacks
-    ``is_ready`` -- callers then simply block, the pre-overlap behavior."""
-    try:
-        return bool(x.is_ready())
-    except AttributeError:  # pragma: no cover - older jax runtimes
-        return True
+    fetch without stalling)."""
+    return x.is_ready()
 
 
 class ListDispatcher:
@@ -830,9 +818,9 @@ class ListDispatcher:
             try:
                 return fault_retry.call(
                     lambda b=backend: self._list_once(A, cand, cap, b),
-                    policy=self.retry_policy, retry_on=(Exception,),
+                    policy=self.retry_policy,
                     token="list.launch", on_retry=self._note_retry)
-            except Exception:
+            except fault_retry.RECOVERABLE:
                 nxt = fault_retry.demote("list", backend)
                 self._note_demotion(backend, nxt)
                 if nxt is None:
@@ -850,7 +838,7 @@ class ListDispatcher:
                                self.devices[0])
             cand = jax.device_put(engine_jax.bucket_rows(batch.cand),
                                   self.devices[0])
-        except Exception:
+        except fault_retry.RECOVERABLE:
             from ..core import listing
 
             self._note_demotion(self.backend, None)
@@ -908,9 +896,9 @@ class ListDispatcher:
             try:
                 A, cand = fault_retry.call(
                     lambda: self._stage(batch, d), policy=self.retry_policy,
-                    retry_on=(Exception,), token="list.stage",
+                    token="list.stage",
                     on_retry=self._note_retry)
-            except Exception:
+            except fault_retry.RECOVERABLE:
                 A = cand = None
                 self._note_demotion(self.backend, None)
             sized = self.capacity is None or self.capacity == "sized"
@@ -922,9 +910,9 @@ class ListDispatcher:
                         # promotion time
                         hard = fault_retry.call(
                             lambda: self._count_pass(A, cand),
-                            policy=self.retry_policy, retry_on=(Exception,),
+                            policy=self.retry_policy,
                             token="list.sizing", on_retry=self._note_retry)
-                    except Exception:
+                    except fault_retry.RECOVERABLE:
                         # sizing rung dead: the whole batch is listed on
                         # the host at promotion time (keeps FIFO order)
                         self._note_demotion(self.backend, None)
@@ -978,7 +966,7 @@ class ListDispatcher:
             with trace.span("device/sizing", B=batch.B, T=batch.T):
                 try:
                     counts = np.asarray(hard)  # blocks only until THIS batch
-                except Exception as exc:
+                except fault_retry.RECOVERABLE as exc:
                     # count pass lost in flight: host-list the whole batch
                     self._note_retry(1, exc)
                     self._note_demotion(self.backend, None)
@@ -1032,7 +1020,7 @@ class ListDispatcher:
             fault_retry.consume("device.harvest", on_retry=self._note_retry)
             try:
                 bufs, cnt, ovf = (np.asarray(x)[: batch.B] for x in out)
-            except Exception as exc:
+            except fault_retry.RECOVERABLE as exc:
                 self._note_retry(1, exc)
                 relaunched = True
                 cap = min(self._cap_ratchet.get(batch.T, SPECULATIVE_CAP0),
@@ -1040,7 +1028,7 @@ class ListDispatcher:
                 out2 = self._relaunch_sync(batch, cap)
                 try:
                     bufs, cnt, ovf = (np.asarray(x)[: batch.B] for x in out2)
-                except Exception:
+                except fault_retry.RECOVERABLE:
                     self._note_demotion(self.backend, None)
                     bufs, cnt, ovf = listing.host_list_triple(batch, self.l)
         if self.capacity == "speculative" or relaunched:
@@ -1066,7 +1054,7 @@ class ListDispatcher:
                         bufs, cnt, ovf = (
                             np.asarray(x)[: batch.B] for x in out2
                         )
-                    except Exception as exc:
+                    except fault_retry.RECOVERABLE as exc:
                         self._note_retry(1, exc)
                         self._note_demotion(self.backend, None)
                         bufs, cnt, ovf = listing.host_list_triple(

@@ -1,19 +1,20 @@
 """Persistent tuning cache + JAX compilation cache wiring.
 
 One directory (the ``--tune-cache DIR`` knob, or the ``REPRO_TUNE_CACHE``
-environment variable) holds everything a warm process needs to skip every
-one-time cost:
+environment variable) holds the tuning records:
+``<dir>/records/<digest>/`` -- one :class:`~repro.tune.records.TuningRecord`
+per kernel-signature/geometry key, written atomically through the
+format-versioned :mod:`repro.checkpoint.store` writer (tmp dir +
+``os.replace`` + ``COMMITTED`` marker).  Concurrent writers of the same
+key race benignly: ``os.replace`` is atomic, the last committed record
+wins, and a reader never observes a partial write.
 
-* ``<dir>/records/<digest>/`` -- one :class:`~repro.tune.records.TuningRecord`
-  per kernel-signature/geometry key, written atomically through the
-  format-versioned :mod:`repro.checkpoint.store` writer (tmp dir +
-  ``os.replace`` + ``COMMITTED`` marker).  Concurrent writers of the same
-  key race benignly: ``os.replace`` is atomic, the last committed record
-  wins, and a reader never observes a partial write.
-* ``<dir>/xla/`` -- JAX's persistent compilation cache, enabled the first
-  time a tune-cache directory is configured, so every XLA executable the
-  kernels key on ``(mode, l, T, W, capacity, B)`` is compiled once per
-  *machine*, not once per process.
+JAX's persistent compilation cache is separate
+(:func:`enable_compilation_cache`, called by every entry-point script):
+it lives where ``JAX_COMPILATION_CACHE_DIR`` says, else at the fixed
+:data:`REPO_COMPILE_CACHE` inside the checkout, so every XLA executable
+the kernels key on ``(mode, l, T, W, capacity, B)`` is compiled once per
+checkout, not once per process.
 
 Read path: an in-process dict in front of the on-disk store.  A corrupt,
 stale-format, or foreign record reads as absent -- the caller falls back
@@ -34,20 +35,30 @@ from .records import TuningRecord
 #: environment knob: equivalent to calling :func:`configure` at startup
 ENV_TUNE_CACHE = "REPRO_TUNE_CACHE"
 
+#: JAX's own knob for its persistent compilation cache directory
+ENV_COMPILE_CACHE = "JAX_COMPILATION_CACHE_DIR"
+
+#: where the compilation cache lives when ``JAX_COMPILATION_CACHE_DIR`` is
+#: not set: one fixed path inside the checkout (gitignored), because the
+#: path is part of what a later process must find again
+REPO_COMPILE_CACHE = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), os.pardir, os.pardir, os.pardir,
+    ".jax_cache"))
+
 _LOCK = threading.Lock()
 _DIR: Optional[str] = None
 _ENV_CHECKED = False
-_XLA_ENABLED = False
 _MEM: Dict[str, TuningRecord] = {}
 
 
 def configure(directory: Optional[str], *, xla_cache: bool = True) -> None:
     """Activate a persistent tune-cache directory for this process.
 
-    Enables the JAX persistent compilation cache under ``<dir>/xla`` (once
-    per process; ``xla_cache=False`` skips it, for tests that must not
-    mutate global jax config).  ``None`` deactivates the on-disk layer
-    (the in-process dict survives).
+    With ``xla_cache`` (the default) the persistent compilation cache is
+    turned on too (:func:`enable_compilation_cache`; its directory does
+    not depend on ``directory``).  ``xla_cache=False`` skips it, for tests
+    that must not mutate global jax config.  ``None`` deactivates the
+    on-disk record layer (the in-process dict survives).
     """
     global _DIR, _ENV_CHECKED
     with _LOCK:
@@ -59,7 +70,7 @@ def configure(directory: Optional[str], *, xla_cache: bool = True) -> None:
         os.makedirs(os.path.join(directory, "records"), exist_ok=True)
         _DIR = directory
     if xla_cache:
-        enable_compilation_cache(os.path.join(directory, "xla"))
+        enable_compilation_cache()
 
 
 def active_dir() -> Optional[str]:
@@ -75,29 +86,25 @@ def active_dir() -> Optional[str]:
     return _DIR
 
 
-def enable_compilation_cache(directory: str) -> bool:
-    """Point JAX's persistent compilation cache at ``directory``.
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-    Thresholds are dropped to zero so even the small fixed-shape clique
-    kernels persist (default jax only caches compiles > 1s).  Idempotent;
-    returns False (and leaves config untouched) on jax builds without the
-    persistent cache.  Safe to call after backend initialization -- the
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is the
+    cache, as JAX reads it itself, and no other is set.  Otherwise the
+    cache goes to :data:`REPO_COMPILE_CACHE`.  Either way the size and
+    compile-time thresholds drop to zero, so even the small fixed-shape
+    clique kernels persist (by default JAX only caches compiles > 1s).
+    Idempotent, and safe to call after backend initialization -- the
     cache is consulted per compile, not at startup.
     """
-    global _XLA_ENABLED
-    if _XLA_ENABLED:
-        return True
     import jax
 
-    try:
-        os.makedirs(directory, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", directory)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:  # pragma: no cover - jax without the persistent cache
-        return False
-    _XLA_ENABLED = True
-    return True
+    directory = os.environ.get(ENV_COMPILE_CACHE) or REPO_COMPILE_CACHE
+    os.makedirs(directory, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return directory
 
 
 def clear_memory() -> None:

@@ -2,8 +2,8 @@
 
 Two searches live here:
 
-* :func:`microbench_backend` -- the lax-vs-pallas winner for one kernel
-  signature, on a tiny synthetic half-dense batch (compile excluded via a
+* :func:`microbench_backend` -- the fastest compiling backend for one
+  kernel signature, on a tiny synthetic half-dense batch (compile excluded via a
   warmup call).  ``backend="autotune"`` (:mod:`repro.kernels.ops`) calls
   this only when no persisted record answers first.
 * :func:`tune_geometry` -- budgeted coordinate descent over the shape
@@ -149,9 +149,11 @@ def resolve_geometry(mode: str, l: int, *,
 def microbench_backend(mode: str, l: int, T: int,
                        capacity: Optional[int] = None,
                        trials: int = 2) -> Tuple[str, Dict[str, float]]:
-    """Fastest of lax vs pallas for one kernel signature.
+    """Fastest backend for one kernel signature.
 
-    Runs each candidate on a tiny synthetic half-dense batch (compile
+    The candidates are the backends that compile for the default device
+    (:func:`repro.kernels.ops.compiling_backends`; on a TPU that is lax
+    alone).  Runs each candidate on a tiny synthetic half-dense batch (compile
     excluded via a warmup call) at the *requested* capacity regime --
     the emit buffer rides the DFS carry, so a winner measured at
     capacity 64 says nothing about capacity 16384.  Returns
@@ -171,7 +173,7 @@ def microbench_backend(mode: str, l: int, T: int,
     cand = pack_bits(np.ones((B, T), dtype=bool))
     cap = min(int(capacity), DEFAULT_MAX_CAPACITY) if capacity else 64
     times: Dict[str, float] = {}
-    for b in ("lax", "pallas"):
+    for b in kops.compiling_backends():
         def run():
             if mode == "list":
                 return kops.list_tiles(A, cand, l, capacity=cap, backend=b)

@@ -15,6 +15,7 @@ the multi-device dispatch paths too (the CI matrix does both 1 and 4).
 import logging
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -342,6 +343,91 @@ def test_kernel_launch_certain_failure_demotes_to_exact_host():
     assert res.stats.demotions > 0
     assert np.array_equal(rows, want_rows)
     assert lres.stats.demotions > 0
+
+
+def _batches(g, k):
+    plan = pipeline.build_plan(g, "hybrid")
+    return [b for b in pipeline.stream_batches(plan, k, order="hybrid")
+            if isinstance(b, pipeline.TileBatch)]
+
+
+@pytest.mark.parametrize("error", [NotImplementedError, ValueError,
+                                   TypeError])
+def test_compile_error_propagates_from_count_dispatcher(monkeypatch, error):
+    """A lowering/compile error is a fault of the program, not of the
+    device: it must surface, never demote to another backend."""
+    def refuse(self, batch, d, backend):
+        raise error("the lowering refuses this kernel")
+
+    monkeypatch.setattr(dsp.Dispatcher, "_launch_on", refuse)
+    stats = Stats()
+    disp = dsp.Dispatcher(3, 1, stats=stats)
+    with pytest.raises(error, match="lowering refuses"):
+        for batch in _batches(make_graph(), 5):
+            disp.submit(batch)
+        disp.finish()
+    assert stats.demotions == 0 and stats.retries == 0
+
+
+@pytest.mark.parametrize("site", ["_count_pass", "_list_once"])
+@pytest.mark.parametrize("error", [NotImplementedError, ValueError])
+def test_compile_error_propagates_from_list_dispatcher(monkeypatch, site,
+                                                       error):
+    def refuse(self, *args):
+        raise error("the lowering refuses this kernel")
+
+    monkeypatch.setattr(dsp.ListDispatcher, site, refuse)
+    stats = Stats()
+    disp = dsp.ListDispatcher(3, 1, sink=listing.ArraySink(5), stats=stats)
+    try:
+        with pytest.raises(error, match="lowering refuses"):
+            for batch in _batches(make_graph(), 5):
+                disp.submit(batch)
+            disp.finish()
+    finally:
+        disp.close()
+    assert stats.demotions == 0 and stats.retries == 0
+
+
+@pytest.mark.parametrize("mode", ["count", "list"])
+def test_runtime_error_and_injected_fault_ride_the_ladder(monkeypatch, mode):
+    """What the device reports while running (a JaxRuntimeError) and an
+    injected kernel.launch fault stay recoverable: retried or demoted,
+    with output byte-identical to the fault-free run."""
+    g = make_graph(seed=4)
+
+    def observe():
+        if mode == "count":
+            res = engine_jax.count(g, 5, devices=1)
+            return res.stats, res.count
+        sink = listing.ArraySink(5)
+        res = listing.stream_cliques(g, 5, sink, devices=1)
+        return res.stats, sink.result()
+
+    _, want = observe()
+    # a JAX runtime failure on the first launch: retried in place
+    cls = dsp.Dispatcher if mode == "count" else dsp.ListDispatcher
+    site = "_launch_on" if mode == "count" else "_list_once"
+    real = getattr(cls, site)
+    calls = []
+
+    def flaky(self, *args):
+        calls.append(1)
+        if len(calls) == 1:
+            raise jax.errors.JaxRuntimeError("INTERNAL: device lost")
+        return real(self, *args)
+
+    monkeypatch.setattr(cls, site, flaky)
+    stats, got = observe()
+    assert stats.retries >= 1 and stats.demotions == 0
+    assert np.array_equal(got, want)
+    monkeypatch.setattr(cls, site, real)
+    # an injected launch fault at every call: demoted down to the host
+    inject.configure("seed=3;kernel.launch=1.0")
+    stats, got = observe()
+    inject.configure(None)
+    assert stats.demotions > 0
+    assert np.array_equal(got, want)
 
 
 def test_chaos_serve_mixed_workload_byte_identical():

@@ -1,6 +1,7 @@
 """End-to-end behaviour tests for the paper's system."""
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from repro.core import ebbkc, engine_jax, vbbkc
 from repro.data import planted_cliques, powerlaw_graph
@@ -58,3 +59,27 @@ def test_listing_service_bounded_output():
     for row in out[:50].tolist():
         for a, b in combinations(row, 2):
             assert b in adj[a]
+
+
+@pytest.mark.parametrize("mode", [[], ["--list"]])
+def test_launcher_verify_fails_on_mismatch(monkeypatch, capsys, mode):
+    """``--verify`` prints the comparison and exits non-zero on a
+    mismatch, so a wrong device answer cannot end a run with status 0."""
+    from repro.launch import clique
+
+    argv = ["clique", "--graph", "er:60,0.3", "--k", "4", "--devices", "1",
+            "--verify"] + mode
+    monkeypatch.setattr("sys.argv", argv)
+    clique.main()  # honest host count: matches, returns normally
+    assert "match=True" in capsys.readouterr().out
+    real = ebbkc.count
+
+    def off_by_one(*args, **kwargs):
+        res = real(*args, **kwargs)
+        res.count += 1
+        return res
+
+    monkeypatch.setattr(clique.ebbkc, "count", off_by_one)
+    with pytest.raises(SystemExit, match="verify failed"):
+        clique.main()
+    assert "match=False" in capsys.readouterr().out

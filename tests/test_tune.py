@@ -232,3 +232,55 @@ def test_drain_tune_events_never_clobbers_verdict(tune_dir):
     # drain) must leave the verdict and the seconds untouched
     ops.drain_tune_events(st)
     assert st.tune_cache_hit is True and st.tune_s == 0.5
+
+
+@pytest.fixture
+def jax_cache_config():
+    """Restore the global compilation-cache config after a test moves it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_persistent_cache_min_compile_time_secs")
+    prev = {n: getattr(jax.config, n) for n in names}
+    yield jax.config
+    for n, v in prev.items():
+        jax.config.update(n, v)
+    cc.reset_cache()
+
+
+def test_compile_cache_honours_env_dir(tmp_path, monkeypatch,
+                                       jax_cache_config):
+    d = str(tmp_path / "from_env")
+    monkeypatch.setenv(tcache.ENV_COMPILE_CACHE, d)
+    assert tune.enable_compilation_cache() == d
+    assert jax_cache_config.jax_compilation_cache_dir == d
+    assert jax_cache_config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert os.path.isdir(d)
+    # a tune-cache directory no longer redirects the compile cache
+    tune.configure(str(tmp_path / "tc"))
+    try:
+        assert jax_cache_config.jax_compilation_cache_dir == d
+    finally:
+        tune.configure(None)
+
+
+def test_compile_cache_defaults_to_fixed_repo_path(monkeypatch,
+                                                   jax_cache_config):
+    monkeypatch.delenv(tcache.ENV_COMPILE_CACHE, raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".jax_cache")
+    assert tcache.REPO_COMPILE_CACHE == want
+    assert tune.enable_compilation_cache() == want
+    assert jax_cache_config.jax_compilation_cache_dir == want
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_autotune_times_only_compiling_backends(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    winner, times = tsearch.microbench_backend("count", 3, 32, trials=1)
+    assert winner == "lax" and set(times) == {"lax"}
