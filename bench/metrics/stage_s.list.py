@@ -1,0 +1,11 @@
+"""stage_s.list.
+
+Stage clock 'stage' per list job: seconds of the dispatcher's
+device/stage spans, the device_put of each batch plus its launch
+(runtime/dispatch.py).
+"""
+from bench.clocks import clock
+
+
+def read(run):
+    return clock(run, "list", "stage")

@@ -66,6 +66,13 @@ class PackedTiles:
     cand: np.ndarray   # (B, W) uint32
 
 
+def fetched_bytes(arrays) -> int:
+    """Bytes a host fetch of ``arrays`` copies from the device (the
+    ``Stats.d2h_bytes`` increment); host arrays (a host-rung result)
+    cost nothing."""
+    return sum(int(x.nbytes) for x in arrays if isinstance(x, jax.Array))
+
+
 def pack_tiles(tiles: List[tiles_mod.Tile], T: int) -> PackedTiles:
     """Pack ``tiles`` into one fixed-shape ``(B, T, W)`` bitset batch."""
     B = len(tiles)
@@ -183,8 +190,13 @@ def count_packed(A: jax.Array, cand: jax.Array, l: int,
     return hard, nv, t, f
 
 
-def combine_counts(hard, nv, t, f, l: int, et: bool) -> int:
-    """Host-exact combination of the device step outputs."""
+def combine_counts(hard, nv, t, f, l: int, et: bool,
+                   stats: Optional[Stats] = None) -> int:
+    """Host-exact combination of the device step outputs; with ``stats``,
+    the bytes it fetches from the device add to ``stats.d2h_bytes``."""
+    if stats is not None:
+        stats.d2h_bytes += fetched_bytes(
+            (hard,) if not et or l <= 2 else (hard, nv, t, f))
     hard = np.asarray(hard).astype(np.int64)
     if not et or l <= 2:
         return int(hard.sum())
@@ -331,7 +343,7 @@ def count(g: Graph, k: int, order: str = "hybrid", et_t: int = 3,
             # async dispatch: block so device time is not billed to combine
             jax.block_until_ready((hard, nv, t, f))
         t1 = time.perf_counter()
-        total += combine_counts(hard, nv, t, f, l, et)
+        total += combine_counts(hard, nv, t, f, l, et, stats=stats)
         if stage_times is not None:
             stage_times["device"] = stage_times.get("device", 0.) + t1 - t0
             stage_times["combine"] = stage_times.get("combine", 0.) \

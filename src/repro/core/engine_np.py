@@ -102,6 +102,9 @@ class Stats:
     # (B,W) candidate masks); the roofline bandwidth denominator paired
     # with device_flops
     device_bytes: Dict[int, int] = dataclasses.field(default_factory=dict)
+    # bytes fetched from the devices to the host: count partials, sizing
+    # counts, and the listing's emit buffers, counts and overflow flags
+    d2h_bytes: int = 0
 
     # How each field combines across Stats objects (Stats.merge) and how it
     # publishes to the metrics registry (repro.obs.metrics.observe_stats):
@@ -146,6 +149,7 @@ class Stats:
         "delta_touched_edges": "sum",
         "tune_s": "sum",
         "tune_cache_hit": "or",
+        "d2h_bytes": "sum",
     }
     # Metric-publication view of the same table (repro.obs reads this).
     _METRIC_KINDS = dict(

@@ -343,31 +343,29 @@ def list_batch(
     zero-candidate lanes count 0, never overflow, and are sliced off
     before decode.
     """
-    from .engine_jax import bucket_rows
+    from .engine_jax import bucket_rows, fetched_bytes
 
     B = batch.B
     A = jnp.asarray(bucket_rows(batch.A))
     cand = jnp.asarray(bucket_rows(batch.cand))
     if capacity is None:
-        with trace.span("device/sizing", B=B, T=batch.T):
-            counts = np.asarray(
-                kops.count_tiles(
-                    A, cand, l, backend=backend, interpret=interpret
-                )
+        with trace.span("device/sizing", batch=batch.index, B=B, T=batch.T):
+            hard = kops.count_tiles(
+                A, cand, l, backend=backend, interpret=interpret
             )
+            counts = np.asarray(hard)
+            stats.d2h_bytes += fetched_bytes((hard,))
         cap = capacity_for(counts, max_capacity, policy=cap_policy)
     else:
         cap = max(1, int(capacity))
-    with trace.span("device/wait", B=B, T=batch.T, capacity=cap):
-        bufs, cnt, ovf = kops.list_tiles(
+    with trace.span("device/wait", batch=batch.index, B=B, T=batch.T,
+                    capacity=cap):
+        out = kops.list_tiles(
             A, cand, l, capacity=cap, backend=backend, interpret=interpret
         )
-        bufs, cnt, ovf = (
-            np.asarray(bufs)[:B],
-            np.asarray(cnt)[:B],
-            np.asarray(ovf)[:B],
-        )
-    with trace.span("decode", B=B, T=batch.T):
+        bufs, cnt, ovf = (np.asarray(x)[:B] for x in out)
+        stats.d2h_bytes += fetched_bytes(out)
+    with trace.span("decode", batch=batch.index, B=B, T=batch.T):
         return decode_batch(batch, bufs, cnt, ovf, l, stats, et_t=et_t)
 
 

@@ -644,6 +644,7 @@ class TileBatch:
     nedges: np.ndarray   # (B,) int32 tile edge counts (cost-model input)
     anchors: np.ndarray  # (B, 2) int64 anchor vertices
     verts: np.ndarray    # (B, T) int64 local slot -> global vertex id
+    index: int = -1      # position in the job's stream (the spans' batch id)
 
     @property
     def B(self) -> int:
@@ -652,7 +653,7 @@ class TileBatch:
 
 
 def _pack_batch(g: Graph, table: TileTable, ids: np.ndarray, T: int,
-                mode: str) -> TileBatch:
+                mode: str, index: int = -1) -> TileBatch:
     # pure function of (table, ids): an injected pack fault is absorbed
     # by in-place retry before any work happens, so results never change
     fault_retry.consume("pack")
@@ -663,7 +664,8 @@ def _pack_batch(g: Graph, table: TileTable, ids: np.ndarray, T: int,
     A = _pack_bits(D)
     cand = _pack_bits(np.arange(T)[None, :] < sz[:, None])
     return TileBatch(T, A, cand, sz.astype(np.int32),
-                     nedges.astype(np.int32), table.anchors[ids].copy(), V)
+                     nedges.astype(np.int32), table.anchors[ids].copy(), V,
+                     index)
 
 
 def _tiles_from_ids(g: Graph, table: TileTable, ids: np.ndarray,
@@ -755,7 +757,10 @@ def stream_batches(source: Union[Graph, PipelinePlan], k: int,
     Tiles wider than ``bins[-1]`` are yielded as :class:`Tile` objects for
     the caller to spill to the host recursion.  When ``timings`` is given,
     "extract" (table build + select) and "pack" seconds are accumulated
-    into it.
+    into it, and with a pack pool "pack_wait": the consumer's seconds
+    blocked on the pool (its ``pack/wait`` spans).  Each packed batch
+    carries its position in the stream as ``TileBatch.index``, the
+    ``batch`` id of its spans here and in the dispatchers.
 
     ``pack_workers`` turns the serial packer into a producer/consumer
     pipeline: a thread pool packs up to ``prefetch`` chunks ahead of the
@@ -817,41 +822,48 @@ def stream_batches(source: Union[Graph, PipelinePlan], k: int,
         # report what actually ran: the <=1-work-item fallback is serial
         stats.pack_workers = 0 if serial else workers
     if serial:
-        for T, chunk in work:
+        for i, (T, chunk) in enumerate(work):
             t1 = time.perf_counter()
-            with trace.span("pack", T=T, tiles=len(chunk)):
-                batch = _pack_batch(plan.g, table, chunk, T, order)
+            with trace.span("pack", batch=i, T=T, tiles=len(chunk)):
+                batch = _pack_batch(plan.g, table, chunk, T, order, i)
             bill_pack(time.perf_counter() - t1)
             yield batch
         return
 
-    def pack_job(T: int, chunk: np.ndarray) -> Tuple[TileBatch, float]:
+    def pack_job(i: int, T: int, chunk: np.ndarray
+                 ) -> Tuple[TileBatch, float]:
         t1 = time.perf_counter()
-        with trace.span("pack", T=T, tiles=len(chunk)):
-            batch = _pack_batch(plan.g, table, chunk, T, order)
+        with trace.span("pack", batch=i, T=T, tiles=len(chunk)):
+            batch = _pack_batch(plan.g, table, chunk, T, order, i)
         return batch, time.perf_counter() - t1
 
     depth = max(2, 2 * workers) if prefetch is None else max(1, int(prefetch))
     occ_sum, occ_n, occ_peak = 0.0, 0, 0
+    if timings is not None:
+        timings.setdefault("pack_wait", 0.0)
     ex = concurrent.futures.ThreadPoolExecutor(max_workers=workers)
     try:
-        it = iter(work)
+        it = enumerate(work)
         futs: Deque = collections.deque(
-            ex.submit(pack_job, T, chunk)
-            for T, chunk in itertools.islice(it, depth))
+            (i, ex.submit(pack_job, i, T, chunk))
+            for i, (T, chunk) in itertools.islice(it, depth))
         while futs:
             occ_peak = max(occ_peak, len(futs))
             occ_sum += len(futs) / depth
             occ_n += 1
-            fut = futs.popleft()
+            i, fut = futs.popleft()
             if fut.done():
                 batch, dt = fut.result()
             else:
-                with trace.span("pack/wait", depth=len(futs) + 1):
+                t1 = time.perf_counter()
+                with trace.span("pack/wait", batch=i, depth=len(futs) + 1):
                     batch, dt = fut.result()
+                if timings is not None:
+                    timings["pack_wait"] += time.perf_counter() - t1
             nxt = next(it, None)
             if nxt is not None:
-                futs.append(ex.submit(pack_job, *nxt))
+                i, (T, chunk) = nxt
+                futs.append((i, ex.submit(pack_job, i, T, chunk)))
             bill_pack(dt)
             yield batch
     finally:

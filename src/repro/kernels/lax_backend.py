@@ -227,7 +227,6 @@ def _list_tile_dfs(A, cand, gt, l: int, capacity: int):
     return buf, count
 
 
-@functools.partial(jax.jit, static_argnames=("l", "capacity"))
 def _list_batch(A, cand, l: int, capacity: int):
     B, T, W = A.shape
     gt = jnp.asarray(gt_masks_np(T))
@@ -254,6 +253,18 @@ def _list_batch(A, cand, l: int, capacity: int):
     return buf, count, overflow
 
 
+@functools.lru_cache(maxsize=None)
+def _list_step(l: int):
+    """The jitted :func:`_list_batch` for ``l``, compiled as the module
+    ``jit_list_l<l>`` so a profiler trace names it."""
+
+    def step(A, cand, capacity: int):
+        return _list_batch(A, cand, l, capacity)
+
+    step.__name__ = step.__qualname__ = f"list_l{l}"
+    return jax.jit(step, static_argnames=("capacity",))
+
+
 def list_tiles(A: jax.Array, cand: jax.Array, l: int, capacity: int):
     """List l-cliques per tile into fixed-capacity local-id buffers.
 
@@ -276,8 +287,9 @@ def list_tiles(A: jax.Array, cand: jax.Array, l: int, capacity: int):
     chunk = min(_pow2_ceil(max(B, 1)), _list_chunk_lanes(T, l))
     Bp = -(-B // chunk) * chunk
     A, cand = _pad_lanes(A, cand, Bp)
+    step = _list_step(l)
     outs = [
-        _list_batch(A[i:i + chunk], cand[i:i + chunk], l, capacity)
+        step(A[i:i + chunk], cand[i:i + chunk], capacity=capacity)
         for i in range(0, Bp, chunk)
     ]
     if len(outs) == 1:

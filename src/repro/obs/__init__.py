@@ -1,7 +1,7 @@
 """Unified observability layer: tracing, metrics, logging, profiling.
 
 ``repro.obs`` sits at the bottom of the import DAG (stdlib only; jax is
-imported lazily inside :mod:`repro.obs.profile`), so every other tier --
+imported lazily by ``trace.configure(enabled=True)``), so every other tier --
 ``core``, ``runtime``, ``serve``, ``tune``, the launchers and benchmarks
 -- can instrument itself without new dependencies or cycles.
 
@@ -13,6 +13,9 @@ Quickstart::
     with trace.span("pack", T=64):
         ...
     trace.export("trace.json")          # open in https://ui.perfetto.dev
+
+    # while tracing is on, spans also land in a running jax.profiler
+    # session, as ``repro/<name>`` annotations
 
     metrics.REGISTRY.counter("repro_batches_total").inc()
 

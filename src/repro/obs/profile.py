@@ -1,158 +1,30 @@
-"""Profiling hooks: jax.profiler capture + per-kernel-signature attribution.
+"""Kernel compile attribution.
 
-Two facilities:
-
-* :func:`profile_span` -- a context manager that opens a tracer span and,
-  when given an output directory, additionally captures a ``jax.profiler``
-  trace scoped to that span (viewable in Perfetto/TensorBoard).  JAX is
-  imported lazily so this module stays importable without it.
-* Kernel attribution -- ``kernels/ops.py`` reports first-call compile
-  times and the dispatcher reports per-batch device waits here, keyed by
-  kernel signature (op, l, T, B, backend).  ``kernel_records()`` returns
-  the aggregate table that ``benchmarks/roofline_report.py`` renders as a
-  per-stage roofline; the same numbers flow to the metrics registry as
-  labelled counters.
+``kernels/ops.py`` and the dispatchers report each kernel signature's
+first-call compile time (op, l, T, B, backend) here; it flows to the
+metrics registry as the labelled counter
+``repro_kernel_compile_seconds_total``.  Device time per kernel comes
+from a ``jax.profiler`` trace, not from host clocks: with tracing on,
+the program's spans land in that trace too (``repro.obs.trace``).
 """
 
 from __future__ import annotations
 
-import contextlib
-import threading
-from typing import Any, Dict, List, Optional
+from typing import Optional
 
-from . import trace
 from .metrics import REGISTRY, Registry
 
-__all__ = [
-    "profile_span",
-    "note_kernel",
-    "kernel_records",
-    "reset_kernels",
-    "aggregate_device_spans",
-]
-
-_lock = threading.Lock()
-_kernels: Dict[str, Dict[str, float]] = {}
-
-
-@contextlib.contextmanager
-def profile_span(name: str, out_dir: Optional[str] = None, **args: Any):
-    """Span that optionally wraps a ``jax.profiler`` trace capture.
-
-    With ``out_dir=None`` this is exactly ``trace.span``.  With a
-    directory, a profiler session is started/stopped around the span body;
-    failures to import or start the profiler degrade to a plain span (the
-    span records ``profiler="unavailable"``).
-    """
-    started = False
-    if out_dir is not None:
-        try:
-            import jax
-
-            jax.profiler.start_trace(out_dir)
-            started = True
-        except Exception:
-            args = dict(args, profiler="unavailable")
-    try:
-        with trace.span(name, **args) as sp:
-            yield sp
-    finally:
-        if started:
-            import jax
-
-            jax.profiler.stop_trace()
+__all__ = ["note_kernel"]
 
 
 def note_kernel(
     sig: str,
-    compile_s: float = 0.0,
-    execute_s: float = 0.0,
-    calls: int = 0,
-    flops: float = 0.0,
-    nbytes: float = 0.0,
+    compile_s: float,
     registry: Optional[Registry] = None,
 ) -> None:
-    """Accumulate compile/execute time for one kernel signature."""
-    with _lock:
-        rec = _kernels.setdefault(
-            sig,
-            {
-                "compile_s": 0.0,
-                "execute_s": 0.0,
-                "calls": 0,
-                "flops": 0.0,
-                "bytes": 0.0,
-            },
-        )
-        rec["compile_s"] += compile_s
-        rec["execute_s"] += execute_s
-        rec["calls"] += calls
-        rec["flops"] += flops
-        rec["bytes"] += nbytes
-    reg = registry or REGISTRY
-    if compile_s:
-        reg.counter(
-            "repro_kernel_compile_seconds_total",
-            help="first-call compile time per kernel signature",
-            sig=sig,
-        ).inc(compile_s)
-    if execute_s:
-        reg.counter(
-            "repro_kernel_execute_seconds_total",
-            help="device execute/wait time per kernel signature",
-            sig=sig,
-        ).inc(execute_s)
-
-
-def kernel_records() -> List[Dict[str, Any]]:
-    """Per-signature attribution rows, sorted by execute time (desc)."""
-    with _lock:
-        rows = [dict(rec, sig=sig) for sig, rec in _kernels.items()]
-    rows.sort(key=lambda r: -r["execute_s"])
-    return rows
-
-
-def reset_kernels() -> None:
-    """Clear the attribution table (test isolation)."""
-    with _lock:
-        _kernels.clear()
-
-
-def aggregate_device_spans(doc: Dict[str, Any]) -> List[Dict[str, Any]]:
-    """Fold a Chrome trace doc into per-signature device rows.
-
-    Groups complete events that carry a ``sig`` arg (the dispatcher's
-    device spans) and sums duration/flops/bytes, yielding the same row
-    shape as :func:`kernel_records` so ``roofline_report.py`` can render a
-    roofline from an exported trace file alone.
-    """
-    by_sig: Dict[str, Dict[str, Any]] = {}
-    for ev in doc.get("traceEvents", []):
-        if ev.get("ph") != "X":
-            continue
-        args = ev.get("args") or {}
-        sig = args.get("sig")
-        if not sig:
-            continue
-        rec = by_sig.setdefault(
-            sig,
-            {
-                "sig": sig,
-                "compile_s": 0.0,
-                "execute_s": 0.0,
-                "calls": 0,
-                "flops": 0.0,
-                "bytes": 0.0,
-            },
-        )
-        dur_s = ev.get("dur", 0.0) / 1e6
-        if ev.get("name") == "kernel/compile":
-            rec["compile_s"] += dur_s
-        else:
-            rec["execute_s"] += dur_s
-            rec["calls"] += 1
-        rec["flops"] += float(args.get("flops", 0) or 0)
-        rec["bytes"] += float(args.get("bytes", 0) or 0)
-    rows = list(by_sig.values())
-    rows.sort(key=lambda r: -r["execute_s"])
-    return rows
+    """Add one first-call compile time to the signature's counter."""
+    (registry or REGISTRY).counter(
+        "repro_kernel_compile_seconds_total",
+        help="first-call compile time per kernel signature",
+        sig=sig,
+    ).inc(compile_s)

@@ -16,14 +16,22 @@ Design constraints (see DESIGN.md section 11):
 * **Thread-safe.**  Finished events append to a lock-protected
   ``collections.deque(maxlen=...)``; per-thread nesting state lives in a
   ``threading.local`` so concurrent producers never contend on the stack.
+* **Profiler bridge.**  While tracing is on and a ``jax.profiler``
+  session is recording, every span also opens a
+  ``jax.profiler.TraceAnnotation`` named ``repro/<span name>`` with the
+  span's args, so the session records the program's spans on the same
+  clock as the device's operations.  JAX is imported inside ``configure``
+  only (and not at all where it is missing), so this module still imports
+  without it.
 * **Cross-thread request trees.**  A request's lifecycle hops threads
   (client -> scheduler -> decode worker), so it cannot be a sync span.
   ``async_begin`` / ``async_instant`` / ``async_end`` emit Chrome async
   events (``ph`` = ``b``/``n``/``e``) keyed by an explicit id (the serving
   tier uses the ticket id), which Perfetto renders as one track per id.
 
-Only the standard library is used; this module must not import jax or any
-``repro`` sibling (it sits below everything else in the import DAG).
+Only the standard library is imported at module level; this module must not
+import jax (except lazily, for the profiler bridge) or any ``repro`` sibling
+(it sits below everything else in the import DAG).
 """
 
 from __future__ import annotations
@@ -33,7 +41,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = [
     "span",
@@ -51,7 +59,7 @@ __all__ = [
     "chrome_trace",
     "export",
     "validate_chrome_trace",
-    "stage_durations",
+    "PROFILER_PREFIX",
 ]
 
 # Category assigned to synchronous spans in the chrome export.
@@ -61,6 +69,9 @@ _CAT_SYNC = "repro"
 _CAT_ASYNC = "request"
 
 _DEFAULT_CAPACITY = 262_144
+
+# Prefix of the profiler annotations the bridge opens (``repro/pack/wait``).
+PROFILER_PREFIX = "repro/"
 
 
 class _NoopSpan:
@@ -84,16 +95,19 @@ _NOOP = _NoopSpan()
 class _Span:
     """An open span; records itself into the tracer on ``__exit__``."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_parent", "_tid")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_parent", "_tid", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._ann = None
 
     def set(self, **args: Any) -> None:
         """Attach or update span attributes while the span is open."""
         self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __enter__(self) -> "_Span":
         tls = self._tracer._tls
@@ -103,11 +117,17 @@ class _Span:
         self._parent = stack[-1].name if stack else None
         self._tid = threading.get_ident()
         stack.append(self)
+        annotate = self._tracer.annotate
+        if annotate is not None and annotate.is_enabled():
+            self._ann = annotate(PROFILER_PREFIX + self.name, **self.args)
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: object) -> bool:
         t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         tr = self._tracer
         stack = tr._tls.stack
         # Tolerate exits out of order (shouldn't happen with `with`): pop
@@ -138,6 +158,8 @@ class Tracer:
         self._events: deque = deque(maxlen=capacity)
         self._tls = threading.local()
         self._dropped = 0
+        # the profiler bridge: jax.profiler.TraceAnnotation, or None
+        self.annotate: Optional[Any] = None
 
     # -- recording ---------------------------------------------------------
 
@@ -316,12 +338,26 @@ def enabled() -> bool:
     return _ENABLED
 
 
+def _profiler_annotation() -> Optional[Any]:
+    """``jax.profiler.TraceAnnotation``, or None where jax is missing."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 def configure(enabled: bool = True, capacity: Optional[int] = None) -> Tracer:
-    """Turn tracing on/off; optionally resize (and clear) the ring buffer."""
+    """Turn tracing on/off; optionally resize (and clear) the ring buffer.
+
+    While tracing is on, every span opened during a ``jax.profiler``
+    session is also a ``TraceAnnotation`` named ``repro/<span name>``.
+    """
     global _ENABLED, _TRACER
     if capacity is not None and capacity != _TRACER._events.maxlen:
         _TRACER = Tracer(capacity=capacity)
     _ENABLED = bool(enabled)
+    _TRACER.annotate = _profiler_annotation() if _ENABLED else None
     return _TRACER
 
 
@@ -430,24 +466,3 @@ def validate_chrome_trace(doc: Dict[str, Any]) -> List[str]:
             problems.append(f"async begin without end for id {k}")
     return problems
 
-
-def stage_durations(
-    doc: Dict[str, Any], prefixes: Iterable[str] = ()
-) -> Dict[str, float]:
-    """Sum complete-event durations (seconds) by name, from a trace doc.
-
-    With ``prefixes``, names are bucketed under the first matching prefix
-    (e.g. ``device/stage`` and ``device/harvest`` both land in ``device``).
-    """
-    out: Dict[str, float] = {}
-    pref = tuple(prefixes)
-    for ev in doc.get("traceEvents", []):
-        if ev.get("ph") != "X":
-            continue
-        name = ev["name"]
-        for p in pref:
-            if name == p or name.startswith(p + "/"):
-                name = p
-                break
-        out[name] = out.get(name, 0.0) + ev.get("dur", 0.0) / 1e6
-    return out

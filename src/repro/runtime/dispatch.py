@@ -125,12 +125,15 @@ def _device_step(
     et: bool,
     interpret: Optional[bool],
     backend: Optional[str] = None,
+    name: str = "count",
 ):
     """Process-wide jitted ``count_packed`` step, shared by all dispatchers.
 
     Memoized so repeated queries reuse one jit cache: jit compiles one
     executable per (input shape, device) pair, and a fresh ``jax.jit`` per
-    dispatcher would re-trace the whole kernel on every query.
+    dispatcher would re-trace the whole kernel on every query.  The
+    compiled module is named ``jit_<name>_l<l>`` (``jit_count_l3``), so a
+    profiler trace tells the steps apart.
     """
 
     def step(A, cand):
@@ -138,6 +141,7 @@ def _device_step(
             A, cand, l, method=method, et=et, interpret=interpret, backend=backend
         )
 
+    step.__name__ = step.__qualname__ = f"{name}_l{l}"
     return jax.jit(step)
 
 
@@ -170,6 +174,7 @@ def make_sharded_step(
             backend=backend,
         )
 
+    inner.__name__ = inner.__qualname__ = f"count_l{l}"
     fn = jax.shard_map(
         inner,
         mesh=mesh,
@@ -254,7 +259,6 @@ class _InFlight:
     out: Tuple[jax.Array, jax.Array, jax.Array, jax.Array]
     rows: int = 0  # un-padded batch rows (slice bound for routed harvest)
     route: object = None  # per-request delivery callback, or None
-    T: int = 0  # tile width (profiling attribution)
     batch: object = None  # host TileBatch, kept for resilient re-execution
 
 
@@ -475,7 +479,9 @@ class Dispatcher:
         ``retry_policy``, then demoted down the backend ladder
         (:meth:`_launch`); the batch keeps its FIFO position either way.
         """
-        with trace.span("device/stage", B=batch.B, T=batch.T):
+        t_stage = time.perf_counter()
+        with trace.span("device/stage", batch=batch.index, B=batch.B,
+                        T=batch.T):
             if self.mesh is not None:
                 d = -1
 
@@ -509,13 +515,16 @@ class Dispatcher:
                 out = self._launch(batch, d)
                 per_dev = np.zeros(self.n_devices, dtype=np.int64)
                 per_dev[d] = batch.B
+        if self.stage_times is not None:
+            st = self.stage_times
+            st["stage"] = st.get("stage", 0.0) + time.perf_counter() - t_stage
         self.placements.append(d)
         self.tiles += batch.B
         self._account(per_dev, batch.T)
         if not self._inflight:
             # in-flight window (re)opens now; overlap accrues from here
             self._overlap_mark = time.perf_counter()
-        self._inflight.append(_InFlight(d, out, batch.B, route, batch.T, batch))
+        self._inflight.append(_InFlight(d, out, batch.B, route, batch))
         if not self.async_staging:
             self._drain()
         else:
@@ -524,26 +533,21 @@ class Dispatcher:
 
     def _harvest_one(self) -> None:
         p = self._inflight.popleft()
-        t0 = time.perf_counter()
-        # wall time since the last accounting mark during which work was in
-        # flight and the host was free (packing / combining, not blocked):
-        # an upper bound on the device execution hidden behind host work
-        # (the device may have finished early; measuring true device busy
-        # time would need device-side profiling).  Counting whole
-        # dispatch-to-harvest residencies instead would double-count
-        # concurrent in-flight batches.  Synchronous staging hides nothing
-        # by construction.
-        if self.async_staging:
-            self.stats.staging_overlap_s += max(0.0, t0 - self._overlap_mark)
-        B = int(p.out[0].shape[0])
-        rows = p.rows or B
-        with trace.span(
-            "device/harvest",
-            device=p.device,
-            sig=self._sig(B, p.T),
-            flops=batch_flops(rows, p.T),
-            bytes=batch_bytes(rows, p.T),
-        ):
+        # the "device" clock brackets what the device/harvest span does
+        with trace.span("device/harvest", batch=p.batch.index,
+                        device=p.device):
+            t0 = time.perf_counter()
+            # wall time since the last accounting mark during which work
+            # was in flight and the host was free (packing / combining,
+            # not blocked): an upper bound on the device execution hidden
+            # behind host work (the device may have finished early; the
+            # profiler trace measures true device busy time).  Counting
+            # whole dispatch-to-harvest residencies instead would
+            # double-count concurrent in-flight batches.  Synchronous
+            # staging hides nothing by construction.
+            if self.async_staging:
+                self.stats.staging_overlap_s += max(
+                    0.0, t0 - self._overlap_mark)
             # injected harvest faults are pure (the device result still
             # exists) and absorbed in place; a REAL wait failure means the
             # staged result is lost -- recompute the same batch
@@ -555,22 +559,17 @@ class Dispatcher:
             except fault_retry.RECOVERABLE as exc:
                 self._note_retry(1, exc)
                 p.out = self._launch(p.batch, max(p.device, 0), block=True)
-                B = int(p.out[0].shape[0])
-        t1 = time.perf_counter()
-        obs_profile.note_kernel(
-            self._sig(B, p.T),
-            execute_s=t1 - t0,
-            calls=1,
-            flops=batch_flops(rows, p.T),
-            nbytes=batch_bytes(rows, p.T),
-        )
+            t1 = time.perf_counter()
         self._overlap_mark = t1  # blocked interval [t0, t1] is not overlap
-        with trace.span("combine", routed=p.route is not None):
+        with trace.span("combine", batch=p.batch.index,
+                        routed=p.route is not None):
             if p.route is None:
-                self.total += engine_jax.combine_counts(*p.out, self.l, self.et)
+                self.total += engine_jax.combine_counts(
+                    *p.out, self.l, self.et, stats=self.stats)
             else:
                 # multi-tenant: hand the un-padded partial rows to the owner
                 # (shape padding appends rows, so a head slice removes it)
+                self.stats.d2h_bytes += engine_jax.fetched_bytes(p.out)
                 p.route(*(np.asarray(x)[: p.rows] for x in p.out))
         t2 = time.perf_counter()
         if self.stage_times is not None:
@@ -734,7 +733,8 @@ class ListDispatcher:
         self.devices = resolve_devices(devices)
         # et=False: ``hard`` is then the raw per-tile count for EVERY tile
         # (no 2-plex masking), which is exactly the emit-buffer size input
-        self._count_step = _device_step(l, "auto", False, interpret, backend)
+        self._count_step = _device_step(l, "auto", False, interpret, backend,
+                                        name="list_size")
         self._loads = np.zeros(len(self.devices))
         # count pass in flight, list kernel not yet launched (FIFO)
         self._pending: Deque[Tuple[int, pipeline.TileBatch, tuple]] = (
@@ -883,7 +883,9 @@ class ListDispatcher:
         if route is None and self.sink is None:
             raise ValueError("emit mode requires a CliqueSink (or per-"
                              "batch route callbacks)")
-        with trace.span("device/stage", B=batch.B, T=batch.T):
+        t_stage = time.perf_counter()
+        with trace.span("device/stage", batch=batch.index, B=batch.B,
+                        T=batch.T):
             d = int(np.argmin(self._loads)) if device is None else int(device)
             cost = float(tile_costs(batch.sizes, batch.nedges, self.l).sum())
             self._loads[d] += cost
@@ -928,6 +930,12 @@ class ListDispatcher:
                 else:
                     out = self._launch_list(batch, A, cand, cap)
                 self._inflight.append((d, batch, (A, cand), out, route))
+        if self.stage_times is not None:
+            with self._acct_lock:
+                self.stage_times["stage"] = (
+                    self.stage_times.get("stage", 0.0)
+                    + time.perf_counter() - t_stage
+                )
         self._promote(block=False)
         if not self.async_staging:
             self._drain()
@@ -963,15 +971,18 @@ class ListDispatcher:
                 break
             t0 = time.perf_counter()
             counts = None
-            with trace.span("device/sizing", B=batch.B, T=batch.T):
+            with trace.span("device/sizing", batch=batch.index, B=batch.B,
+                            T=batch.T):
                 try:
                     counts = np.asarray(hard)  # blocks only until THIS batch
                 except fault_retry.RECOVERABLE as exc:
                     # count pass lost in flight: host-list the whole batch
                     self._note_retry(1, exc)
                     self._note_demotion(self.backend, None)
-            if self.stage_times is not None:
-                with self._acct_lock:
+            with self._acct_lock:
+                if counts is not None:
+                    self.stats.d2h_bytes += engine_jax.fetched_bytes((hard,))
+                if self.stage_times is not None:
                     self.stage_times["device"] = (
                         self.stage_times.get("device", 0.0)
                         + time.perf_counter() - t0
@@ -983,7 +994,9 @@ class ListDispatcher:
                 cap = listing.capacity_for(
                     counts, self.max_capacity, policy=self.cap_policy
                 )
-                out = self._launch_list(batch, A, cand, cap)
+                with trace.span("device/launch", batch=batch.index,
+                                capacity=cap):
+                    out = self._launch_list(batch, A, cand, cap)
             self._inflight.append((d, batch, (A, cand), out, route))
             block = False  # only the head is ever forced
 
@@ -1006,20 +1019,15 @@ class ListDispatcher:
         from ..core import listing
 
         t0 = time.perf_counter()
-        sig = (f"list[l={self.l},T={batch.T},B={batch.B},"
-               f"backend={self.backend}]")
         # slice off the bucketing padding (zero-candidate lanes) before
         # ratchet/decode -- padding rows count 0 and never overflow
         relaunched = False
-        with trace.span(
-            "device/wait",
-            sig=sig,
-            flops=batch_flops(batch.B, batch.T),
-            bytes=batch_bytes(batch.B, batch.T),
-        ):
+        fetched = 0  # device bytes copied to the host for this batch
+        with trace.span("device/wait", batch=batch.index):
             fault_retry.consume("device.harvest", on_retry=self._note_retry)
             try:
                 bufs, cnt, ovf = (np.asarray(x)[: batch.B] for x in out)
+                fetched += engine_jax.fetched_bytes(out)
             except fault_retry.RECOVERABLE as exc:
                 self._note_retry(1, exc)
                 relaunched = True
@@ -1028,6 +1036,7 @@ class ListDispatcher:
                 out2 = self._relaunch_sync(batch, cap)
                 try:
                     bufs, cnt, ovf = (np.asarray(x)[: batch.B] for x in out2)
+                    fetched += engine_jax.fetched_bytes(out2)
                 except fault_retry.RECOVERABLE:
                     self._note_demotion(self.backend, None)
                     bufs, cnt, ovf = listing.host_list_triple(batch, self.l)
@@ -1054,6 +1063,7 @@ class ListDispatcher:
                         bufs, cnt, ovf = (
                             np.asarray(x)[: batch.B] for x in out2
                         )
+                        fetched += engine_jax.fetched_bytes(out2)
                     except fault_retry.RECOVERABLE as exc:
                         self._note_retry(1, exc)
                         self._note_demotion(self.backend, None)
@@ -1063,15 +1073,8 @@ class ListDispatcher:
                 with self._acct_lock:
                     self.stats.emit_retries += 1
         t1 = time.perf_counter()
-        obs_profile.note_kernel(
-            sig,
-            execute_s=t1 - t0,
-            calls=1,
-            flops=batch_flops(batch.B, batch.T),
-            nbytes=batch_bytes(batch.B, batch.T),
-        )
         fault_retry.consume("decode", on_retry=self._note_retry)
-        with trace.span("decode", B=batch.B, T=batch.T,
+        with trace.span("decode", batch=batch.index, B=batch.B, T=batch.T,
                         routed=route is not None):
             if route is not None:
                 emitted = int(route(batch, bufs, cnt, ovf))
@@ -1084,6 +1087,7 @@ class ListDispatcher:
         t2 = time.perf_counter()
         with self._acct_lock:
             self.stats.emitted_cliques += emitted
+            self.stats.d2h_bytes += fetched
             if self.stage_times is not None:
                 st = self.stage_times
                 st["device"] = st.get("device", 0.0) + (t1 - t0)
@@ -1114,13 +1118,22 @@ class ListDispatcher:
         # backlog (it holds references to device buffers)
         self._promote(block=False)
         while len(self._decoding) > self._decode_depth:
-            self._decoding.popleft().result()
+            self._wait_decode()
+
+    def _wait_decode(self) -> None:
+        """Block on the oldest decode job (the backlog's back-pressure)."""
+        fut = self._decoding.popleft()
+        if fut.done():
+            fut.result()
+            return
+        with trace.span("decode/wait", depth=len(self._decoding) + 1):
+            fut.result()
 
     def _drain(self) -> None:
         while self._pending or self._inflight:
             self._harvest_one()
         while self._decoding:
-            self._decoding.popleft().result()
+            self._wait_decode()
 
     def consume(self, stream, on_spill=None) -> Tuple[int, int]:
         """Emit-mode twin of :meth:`Dispatcher.consume`.

@@ -9,6 +9,9 @@ and 4).
 
 import dataclasses
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
 from collections import Counter as TallyCounter
@@ -17,13 +20,13 @@ import numpy as np
 import pytest
 
 from conftest import random_graph
-from repro.core import engine_jax, pipeline
+from repro.core import engine_jax, listing, pipeline
 from repro.core.engine_np import Stats
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace
 from repro.obs.export import MetricsServer, render_prometheus, scrape
 from repro.obs.logging import get_logger, setup_logging
-from repro.obs.profile import aggregate_device_spans, note_kernel
+from repro.obs.profile import note_kernel
 from repro.serve import CliqueService
 
 
@@ -213,9 +216,15 @@ def test_engine_trace_covers_device_stages(tracer):
             "combine"} <= names
     doc = trace.chrome_trace()
     assert trace.validate_chrome_trace(doc) == []
-    # device spans carry kernel-signature attribution for the roofline
-    rows = aggregate_device_spans(doc)
-    assert rows and any(r["flops"] > 0 for r in rows)
+    # one batch id follows a batch from its packer through the dispatcher
+    ids = {}
+    for ev in trace.events():
+        if ev["name"] in ("pack", "device/stage", "device/harvest",
+                          "combine"):
+            ids.setdefault(ev["name"], []).append(ev["args"]["batch"])
+    assert sorted(ids["pack"]) == list(range(len(ids["pack"])))
+    for name in ("device/stage", "device/harvest", "combine"):
+        assert sorted(ids[name]) == sorted(ids["pack"]), name
 
 
 # ---------------------------------------------------------------------------
@@ -368,11 +377,110 @@ def test_metrics_server_scrape(registry):
 def test_note_kernel_attribution(registry):
     note_kernel("count[l=3,T=64,B=256,backend=lax]", compile_s=0.5,
                 registry=registry)
-    note_kernel("count[l=3,T=64,B=256,backend=lax]", execute_s=0.25,
-                calls=1, flops=1e9, nbytes=1e6, registry=registry)
-    got = {m.name for m in registry.collect()}
-    assert "repro_kernel_compile_seconds_total" in got
-    assert "repro_kernel_execute_seconds_total" in got
+    note_kernel("count[l=3,T=64,B=256,backend=lax]", compile_s=0.25,
+                registry=registry)
+    got = {(m.name, m.labels): m for m in registry.collect()}
+    key = ("repro_kernel_compile_seconds_total",
+           (("sig", "count[l=3,T=64,B=256,backend=lax]"),))
+    assert got[key].value == pytest.approx(0.75)
+
+
+# ---------------------------------------------------------------------------
+# profiler bridge + device-to-host bytes
+# ---------------------------------------------------------------------------
+
+
+def test_obs_imports_without_jax():
+    # the bridge imports jax inside configure only
+    code = ("import sys; sys.modules['jax'] = None; "
+            "import repro.obs; from repro.obs import trace; "
+            "trace.configure(enabled=True); "
+            "trace.configure(enabled=False)")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120,
+                          env=dict(os.environ, PYTHONPATH=src))
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_profiler_bridge_writes_program_spans(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    g = small_graph(31)
+    engine_jax.count(g, 4, batch_size=16, devices=1)  # compile outside
+    trace.configure(enabled=True)
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            want = engine_jax.count(g, 4, batch_size=16, devices=1).count
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        trace.configure(enabled=False)
+        trace.reset()
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+               for f in fs if f.endswith(".xplane.pb")]
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(trace.PROFILER_PREFIX):
+                    seen.setdefault(ev.name, []).append(dict(ev.stats))
+    assert {"repro/extract", "repro/device/stage", "repro/device/harvest",
+            "repro/combine"} <= set(seen)
+    assert {"repro/pack/wait", "repro/pack"} & set(seen)
+    # the span args ride along: one batch id per staged batch
+    staged = sorted(st["batch"] for st in seen["repro/device/stage"])
+    assert staged == list(range(len(staged)))
+    assert want == engine_jax.count(g, 4, batch_size=16).count
+
+
+def test_bridge_waits_for_a_profiler_session(tracer):
+    # tracing on and no profiler session: the span records, and opens no
+    # annotation
+    with trace.span("pack", batch=0) as sp:
+        assert sp._ann is None
+    assert [ev["name"] for ev in trace.events()] == ["pack"]
+    assert trace.configure(enabled=False).annotate is None
+
+
+@pytest.mark.parametrize("k, outputs", [(4, 1), (5, 4)])
+def test_d2h_bytes_counts_the_partials_a_combine_reads(k, outputs):
+    g = random_graph(np.random.default_rng(7), n_lo=28, n_hi=29, p_lo=0.5,
+                     p_hi=0.5)
+    bs, bins = 16, (32,)
+    res = engine_jax.count(g, k, batch_size=bs, bins=bins, devices=1)
+    # each batch fetches its (B',) 4-byte partials: ``hard`` alone where
+    # no closed form applies (l = 2), all four where it does (l = 3)
+    want = sum(engine_jax.bucket_rows(item.A).shape[0] * 4 * outputs
+               for item in pipeline.stream_batches(g, k, batch_size=bs,
+                                                   bins=bins)
+               if isinstance(item, pipeline.TileBatch))
+    assert want > 0 and res.stats.d2h_bytes == want
+
+
+@pytest.mark.parametrize("devices", [None, 1])
+def test_d2h_bytes_counts_a_listings_fetched_buffers(devices):
+    g = small_graph(7)
+    cap, bs, bins = 16, 16, (32,)
+    sink = listing.ArraySink(4)
+    res = listing.stream_cliques(g, 4, sink, capacity=cap, batch_size=bs,
+                                 bins=bins, devices=devices, pack_workers=0,
+                                 plan_cache=False)
+    # a pinned capacity skips the sizing pass: each batch fetches its
+    # (B', cap, l) int32 emit buffer and (B',) uint32 counts and overflow
+    # flags, B' the batch padded to a power of two
+    want = 0
+    for item in pipeline.stream_batches(g, 4, batch_size=bs, bins=bins):
+        if isinstance(item, pipeline.TileBatch):
+            rows = engine_jax.bucket_rows(item.A).shape[0]
+            want += rows * (cap * 2 + 2) * 4
+    assert want > 0 and res.stats.emitted_cliques == len(sink.result())
+    assert res.stats.d2h_bytes == want
 
 
 # ---------------------------------------------------------------------------
