@@ -104,7 +104,11 @@ class Stats:
     device_bytes: Dict[int, int] = dataclasses.field(default_factory=dict)
     # bytes fetched from the devices to the host: count partials, sizing
     # counts, and the listing's emit buffers, counts and overflow flags
+    # (or, where the device decodes, its compact rows and row total)
     d2h_bytes: int = 0
+    # listed rows decoded on the device (repro.core.listing.decode_on_device)
+    # and handed to the sink; the rest were decoded on the host
+    device_decoded_rows: int = 0
 
     # How each field combines across Stats objects (Stats.merge) and how it
     # publishes to the metrics registry (repro.obs.metrics.observe_stats):
@@ -150,6 +154,7 @@ class Stats:
         "tune_s": "sum",
         "tune_cache_hit": "or",
         "d2h_bytes": "sum",
+        "device_decoded_rows": "sum",
     }
     # Metric-publication view of the same table (repro.obs reads this).
     _METRIC_KINDS = dict(

@@ -4,10 +4,13 @@ The counting engine (:mod:`repro.core.engine_jax`) reduces every tile to a
 scalar; this module is its *output* twin (DESIGN.md section 6).  The same
 streaming tile pipeline feeds the Pallas listing kernel family
 (:mod:`repro.kernels.clique_list`), which materializes each completed
-l-clique's local vertex ids into a fixed-capacity per-tile buffer; the host
-then decodes tile-local ids through the batch's ``verts`` membership table
-back to global vertex ids and streams the rows into a pluggable
-:class:`CliqueSink`.
+l-clique's local vertex ids into a fixed-capacity per-tile buffer.  Decode
+turns tile-local ids through the batch's ``verts`` membership table back
+to global vertex ids, puts the anchors in front and sorts each row; the
+rows then stream into a pluggable :class:`CliqueSink`.  A batch sized by
+the count pass decodes on the device (:func:`decode_on_device`: compact
+sorted rows come back instead of the padded buffer); every other batch
+decodes on the host (:func:`decode_batch`).
 
 Exactness invariants:
 
@@ -28,9 +31,11 @@ Exactness invariants:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from .bitops import unpack_mask
@@ -211,27 +216,35 @@ def list_spilled(
 
 def decode_batch(
     batch: pipeline.TileBatch,
-    bufs: np.ndarray,
+    bufs: Optional[np.ndarray],
     counts: np.ndarray,
     overflow: np.ndarray,
     l: int,
     stats: Stats,
     et_t: int = 3,
+    device_rows: Optional[np.ndarray] = None,
 ) -> np.ndarray:
     """Decode one harvested (buffer, count, overflow) triple to global rows.
 
-    Non-overflowed tiles decode vectorized straight from the kernel buffer;
-    overflowed tiles are re-listed by the host recursion from the packed
-    adjacency (never truncated) and spliced back in tile order.
+    Non-overflowed tiles decode vectorized straight from the kernel buffer
+    -- or, where the device decoded them (``device_rows``: the ``(k, Rp)``
+    int32 array :func:`fetch_decoded` returns; ``bufs`` is then unused), are
+    widened from those; overflowed tiles are re-listed by the host
+    recursion from the packed adjacency (never truncated) and spliced back
+    in tile order.
     """
     counts = np.asarray(counts, dtype=np.int64)
     overflow = np.asarray(overflow)
     counts_eff = np.where(overflow > 0, 0, counts)
-    owner, pos = ragged_expand(counts_eff)
-    local = bufs[owner, pos]  # (n, l) local ids
-    glob = batch.verts[owner[:, None], local]
-    decoded = np.concatenate([batch.anchors[owner], glob], axis=1)
-    decoded = np.sort(decoded, axis=1) if decoded.shape[0] else decoded
+    if device_rows is not None:
+        decoded = np.ascontiguousarray(
+            device_rows[:, : int(counts_eff.sum())].T, dtype=np.int64)
+    else:
+        owner, pos = ragged_expand(counts_eff)
+        local = bufs[owner, pos]  # (n, l) local ids
+        glob = batch.verts[owner[:, None], local]
+        decoded = np.concatenate([batch.anchors[owner], glob], axis=1)
+        decoded = np.sort(decoded, axis=1) if decoded.shape[0] else decoded
     if not overflow.any():
         return decoded
     parts = np.split(decoded, np.cumsum(counts_eff)[:-1])
@@ -244,6 +257,137 @@ def decode_batch(
                 rows, s, batch.anchors[b], batch.verts[b], l, et_t=et_t
             )
     return np.concatenate(parts)
+
+
+# ---------------------------------------------------------------------------
+# decode on the device: sized batches come back as compact sorted rows
+# ---------------------------------------------------------------------------
+
+#: the device decode pads a batch's row count up a ladder of four steps
+#: an octave (under a quarter of slack), never below one 128-lane row
+DECODE_MIN_ROWS = 128
+
+
+def decode_rows_bucket(rows: int) -> int:
+    """Rows of the device decode's output for ``rows`` decoded rows: the
+    smallest m * 2**e >= rows with m in 4..7 (at least ``DECODE_MIN_ROWS``),
+    which bounds both the executables and the padding fetched."""
+    r = max(int(rows), DECODE_MIN_ROWS)
+    e = r.bit_length() - 3
+    return -(-r // (1 << e)) << e
+
+
+def decodes_on_device(batch: pipeline.TileBatch) -> bool:
+    """True when ``batch``'s global ids fit the device decode's int32."""
+    return int(batch.verts.max(initial=0)) < 2 ** 31 and \
+        int(batch.anchors.max(initial=0)) < 2 ** 31
+
+
+def decode_tables(batch: pipeline.TileBatch):
+    """The batch's ``(verts, anchors)`` as bucket-padded int32 host arrays,
+    padded like ``A`` and ``cand`` (``engine_jax.bucket_rows``)."""
+    from .engine_jax import bucket_rows
+
+    return (bucket_rows(batch.verts.astype(np.int32)),
+            bucket_rows(batch.anchors.astype(np.int32)))
+
+
+def _sort_columns(cols: List) -> List:
+    """Odd-even transposition network: column j becomes the j-th smallest."""
+    cols = list(cols)
+    for rnd in range(len(cols)):
+        for i in range(rnd % 2, len(cols) - 1, 2):
+            lo, hi = cols[i], cols[i + 1]
+            cols[i], cols[i + 1] = jnp.minimum(lo, hi), jnp.maximum(lo, hi)
+    return cols
+
+
+@functools.lru_cache(maxsize=None)
+def _decode_step(l: int):
+    """The jitted device decode for ``l``, compiled as ``jit_list_decode_l<l>``.
+
+    (bufs (Bp, cap, l) int32, cnt (Bp,) uint32, ovf (Bp,) uint32,
+    verts (Bp, T) int32, anchors (Bp, 2) int32) -> ((k, Bp*cap + cap)
+    int32, () int32): tile b's rows (the kernel's own counts; overflowed
+    tiles left out), each the anchors plus ``verts[b, local]`` sorted
+    ascending down the k columns, compacted in tile order from column 0,
+    rows on the lane axis; the row total.  Columns past it are padding.
+
+    No gathers: a TPU gather costs tens of ns a row, so global ids come
+    from a compare-select over the tile's T slots, and the compaction
+    writes each tile's block at its offset.
+    """
+
+    def step(bufs, cnt, ovf, verts, anchors):
+        Bp, cap, _ = bufs.shape
+        T = verts.shape[1]
+        n = jnp.where(ovf > 0, 0, cnt).astype(jnp.int32)
+        starts = jnp.cumsum(n) - n
+        slots = jnp.arange(T, dtype=jnp.int32)
+        cols = [jnp.broadcast_to(anchors[:, c, None], (Bp, cap))
+                for c in (0, 1)]
+        for j in range(l):
+            hit = bufs[:, :, j, None] == slots  # (Bp, cap, T)
+            cols.append(jnp.where(hit, verts[:, None, :], 0).sum(axis=2))
+        dense = jnp.stack(_sort_columns(cols), axis=1)  # (Bp, k, cap)
+
+        # a tile's block runs past its rows; the next tile, which starts
+        # where those rows end, writes over the excess
+        def place(b, out):
+            return jax.lax.dynamic_update_slice(out, dense[b], (0, starts[b]))
+
+        out = jnp.zeros((l + 2, Bp * cap + cap), jnp.int32)
+        return jax.lax.fori_loop(0, Bp, place, out, unroll=4), n.sum()
+
+    step.__name__ = step.__qualname__ = f"list_decode_l{l}"
+    return jax.jit(step)
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _first_rows(dense, rows: int):
+    """The first ``rows`` columns of the decode's output (all, if fewer)."""
+    return dense[:, :rows]
+
+
+@dataclasses.dataclass
+class DeviceRows:
+    """One batch decoded on the device, not yet fetched."""
+
+    rows: jax.Array       # (k, Rp) int32; the first ``expected`` columns
+    total: jax.Array      # () int32: the device's row count
+    counts: np.ndarray    # (B,) the sizing pass's per-tile counts
+    overflow: np.ndarray  # (B,) bool: counts past the emit buffer
+    expected: int         # rows of the tiles that fit the buffer
+
+
+def decode_on_device(out, tables, l: int, counts: np.ndarray,
+                     capacity: int, B: int) -> DeviceRows:
+    """Launch the device decode of a list kernel's ``out`` triple.
+
+    ``tables`` are the batch's staged ``decode_tables``; ``counts`` the
+    sizing pass's (Bp,) per-tile counts, ``capacity`` the buffer they
+    sized.  Runs asynchronously; :func:`fetch_decoded` collects it.
+    """
+    counts = np.asarray(counts, dtype=np.int64)[:B]
+    overflow = counts > capacity
+    R = int(counts[~overflow].sum())
+    dense, total = _decode_step(l)(*out, *tables)
+    # fetch R rows on the ladder: a slice per ladder step compiles in a
+    # blink where the decode itself would compile once per step
+    return DeviceRows(_first_rows(dense, rows=decode_rows_bucket(R)), total,
+                      counts, overflow, R)
+
+
+def fetch_decoded(dev: DeviceRows) -> np.ndarray:
+    """Fetch a device decode's (k, Rp) rows; check the device's row total
+    against the host's sizing counts (a mismatch raises)."""
+    rows, total = jax.device_get((dev.rows, dev.total))
+    if int(total) != dev.expected:
+        raise RuntimeError(
+            f"device decode listed {int(total)} rows where the sizing pass "
+            f"counted {dev.expected}: the list kernel and its count pass "
+            f"disagree")
+    return rows
 
 
 # ---------------------------------------------------------------------------
@@ -336,7 +480,9 @@ def list_batch(
     backend: Optional[str] = None,
     et_t: int = 3,
 ) -> np.ndarray:
-    """Single-device emit step: count pass -> sized list kernel -> decode.
+    """Single-device emit step: count pass -> sized list kernel -> decode
+    (on the device where the count pass sized the buffer, as in the
+    dispatcher).
 
     The batch axis is padded to a power of two before the kernels so
     ragged tail chunks reuse the full-batch executables; the padded
@@ -358,15 +504,25 @@ def list_batch(
         cap = capacity_for(counts, max_capacity, policy=cap_policy)
     else:
         cap = max(1, int(capacity))
+    rows = None  # the device decode's rows, where it ran
     with trace.span("device/wait", batch=batch.index, B=B, T=batch.T,
                     capacity=cap):
         out = kops.list_tiles(
             A, cand, l, capacity=cap, backend=backend, interpret=interpret
         )
-        bufs, cnt, ovf = (np.asarray(x)[:B] for x in out)
-        stats.d2h_bytes += fetched_bytes(out)
+        if capacity is None and decodes_on_device(batch):
+            tables = tuple(jnp.asarray(x) for x in decode_tables(batch))
+            dev = decode_on_device(out, tables, l, counts, cap, B)
+            rows = fetch_decoded(dev)
+            bufs, cnt, ovf = None, dev.counts, dev.overflow
+            stats.d2h_bytes += fetched_bytes((dev.rows, dev.total))
+            stats.device_decoded_rows += dev.expected
+        else:
+            bufs, cnt, ovf = (np.asarray(x)[:B] for x in out)
+            stats.d2h_bytes += fetched_bytes(out)
     with trace.span("decode", batch=batch.index, B=B, T=batch.T):
-        return decode_batch(batch, bufs, cnt, ovf, l, stats, et_t=et_t)
+        return decode_batch(batch, bufs, cnt, ovf, l, stats, et_t=et_t,
+                            device_rows=rows)
 
 
 def stream_cliques(
@@ -398,8 +554,14 @@ def stream_cliques(
     The accelerator twin of ``ebbkc.list_cliques(backend="host")``: streams
     capacity-batched packed tiles, runs the listing kernels (sized by a
     first count pass unless ``capacity`` pins the buffer or selects the
-    dispatcher's ``"speculative"`` ratchet mode), decodes on the host,
-    and feeds the sink in deterministic stream order.  ``devices``
+    dispatcher's ``"speculative"`` ratchet mode), decodes the rows, and
+    feeds the sink in deterministic stream order.  A sized batch whose
+    global ids fit int32 decodes on the device (:func:`decode_on_device`,
+    counted in ``Stats.device_decoded_rows``); a pinned or speculative
+    capacity, a routed batch, a batch that a fault sent down to the host
+    rung, ids of 2**31 or more, and the host re-lists of overflowed tiles
+    take the host's :func:`decode_batch`.  The rows are the same bytes
+    either way.  ``devices``
     routes batches through :class:`repro.runtime.dispatch.ListDispatcher`
     (per-device placement, double-buffered staging, FIFO harvest +
     decode-worker overlap -- same knobs as the counting engine).

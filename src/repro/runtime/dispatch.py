@@ -663,6 +663,16 @@ class ListDispatcher:
     Harvest/decode of completed triples overlaps device execution of
     later batches in every mode.
 
+    Where decode runs: in sized mode an un-routed batch whose global ids
+    fit int32 is decoded on its device right after its list kernel
+    (``listing.decode_on_device``: the batch's ``verts``/``anchors`` are
+    staged with it, and the decode worker fetches compact sorted rows,
+    checks their total against the count pass and splices in host
+    re-lists of overflowed tiles).  Speculative and pinned capacities,
+    routed batches, batches served by the host rung, and ids of 2**31 or
+    more decode on the host (``listing.decode_batch``), as does a batch
+    whose decoded rows are lost after launch.
+
     Ordering guarantee: pending batches are promoted strictly FIFO,
     harvested strictly FIFO, and decoded/emitted by **one** decode-worker
     thread consuming a FIFO queue, so decoded rows reach the sink
@@ -777,17 +787,22 @@ class ListDispatcher:
         trace.instant("resilience/demote", frm=frm or self.backend,
                       to=to or "host")
 
-    def _stage(self, batch: pipeline.TileBatch, d: int):
-        """Fire the stage site and device_put the bucketing-padded batch.
+    def _stage(self, batch: pipeline.TileBatch, d: int, tables: bool = False):
+        """Fire the stage site and device_put the bucketing-padded batch,
+        with its decode tables (``listing.decode_tables``) if ``tables``.
 
         The padded zero-candidate lanes are sliced off again in the
         decode job (padding rows count 0 and never overflow).
         """
+        from ..core import listing
+
         inject.fire("device.stage")
-        A = jax.device_put(engine_jax.bucket_rows(batch.A), self.devices[d])
-        cand = jax.device_put(engine_jax.bucket_rows(batch.cand),
-                              self.devices[d])
-        return A, cand
+        host = (engine_jax.bucket_rows(batch.A),
+                engine_jax.bucket_rows(batch.cand))
+        if tables:
+            host += listing.decode_tables(batch)
+        staged = jax.device_put(host, self.devices[d])
+        return staged[0], staged[1], (tuple(staged[2:]) if tables else None)
 
     def _count_pass(self, A, cand):
         """Fire the launch site and start the async sizing count pass."""
@@ -895,15 +910,18 @@ class ListDispatcher:
             per_dev[d] = batch.B
             with self._acct_lock:
                 _account_devices(self.stats, per_dev, batch.T)
+            sized = self.capacity is None or self.capacity == "sized"
+            # sized, un-routed batches decode on the device (_promote)
+            on_device = sized and route is None and \
+                listing.decodes_on_device(batch)
             try:
-                A, cand = fault_retry.call(
-                    lambda: self._stage(batch, d), policy=self.retry_policy,
-                    token="list.stage",
+                A, cand, tables = fault_retry.call(
+                    lambda: self._stage(batch, d, on_device),
+                    policy=self.retry_policy, token="list.stage",
                     on_retry=self._note_retry)
             except fault_retry.RECOVERABLE:
-                A = cand = None
+                A = cand = tables = None
                 self._note_demotion(self.backend, None)
-            sized = self.capacity is None or self.capacity == "sized"
             if sized:
                 hard = None
                 if A is not None:
@@ -918,7 +936,8 @@ class ListDispatcher:
                         # sizing rung dead: the whole batch is listed on
                         # the host at promotion time (keeps FIFO order)
                         self._note_demotion(self.backend, None)
-                self._pending.append((d, batch, (A, cand, hard), route))
+                self._pending.append((d, batch, (A, cand, hard), route,
+                                      tables))
             else:
                 if self.capacity == "speculative":  # ratchet guess
                     cap = min(self._cap_ratchet.get(batch.T, SPECULATIVE_CAP0),
@@ -929,7 +948,7 @@ class ListDispatcher:
                     out = listing.host_list_triple(batch, self.l)
                 else:
                     out = self._launch_list(batch, A, cand, cap)
-                self._inflight.append((d, batch, (A, cand), out, route))
+                self._inflight.append((d, batch, (A, cand), out, route, None))
         if self.stage_times is not None:
             with self._acct_lock:
                 self.stage_times["stage"] = (
@@ -958,13 +977,14 @@ class ListDispatcher:
         from ..core import listing
 
         while self._pending:
-            d, batch, (A, cand, hard), route = self._pending[0]
+            d, batch, (A, cand, hard), route, tables = self._pending[0]
             if hard is None:
                 # sizing (or staging) already exhausted its ladder in
                 # submit: list the batch on the host, keeping FIFO order
                 self._pending.popleft()
                 out = listing.host_list_triple(batch, self.l)
-                self._inflight.append((d, batch, (A, cand), out, route))
+                self._inflight.append((d, batch, (A, cand), out, route,
+                                       None))
                 block = False
                 continue
             if not block and not _is_ready(hard):
@@ -988,6 +1008,7 @@ class ListDispatcher:
                         + time.perf_counter() - t0
                     )
             self._pending.popleft()
+            dev = None
             if counts is None:
                 out = listing.host_list_triple(batch, self.l)
             else:
@@ -997,25 +1018,44 @@ class ListDispatcher:
                 with trace.span("device/launch", batch=batch.index,
                                 capacity=cap):
                     out = self._launch_list(batch, A, cand, cap)
-            self._inflight.append((d, batch, (A, cand), out, route))
+                    if tables is not None and isinstance(out[0], jax.Array):
+                        dev = self._launch_decode(batch, out, tables, counts,
+                                                  cap)
+            self._inflight.append((d, batch, (A, cand), out, route, dev))
             block = False  # only the head is ever forced
 
+    def _launch_decode(self, batch: pipeline.TileBatch, out, tables, counts,
+                       cap: int):
+        """Start the device decode of a device rung's triple; None (the
+        host decodes the triple) if the launch fails."""
+        from ..core import listing
+
+        try:
+            return listing.decode_on_device(out, tables, self.l, counts, cap,
+                                            batch.B)
+        except fault_retry.RECOVERABLE as exc:
+            self._note_retry(1, exc)
+            return None
+
     def _decode_job(self, batch: pipeline.TileBatch, acand: tuple,
-                    out: tuple, route=None) -> None:
+                    out: tuple, route=None, dev=None) -> None:
         """Run one decode job on the decode worker.
 
-        Blocks for the device triple, then either decodes to global rows
-        (incl. overflow re-lists) and feeds the sink, or -- for routed
-        batches -- hands the sliced triple to the owning request's
-        ``route``.  Only this thread ever touches the sink or
-        ``emitted_cliques`` / ``overflowed_tiles``, so FIFO submission ==
-        deterministic sink order with no further synchronization.
+        Where ``_promote`` launched a device decode (``dev``), fetches its
+        compact sorted rows, splices in host re-lists of overflowed tiles
+        and feeds the sink.  Otherwise blocks for the device triple, then
+        either decodes it on the host (``listing.decode_batch``, incl.
+        overflow re-lists) and feeds the sink, or -- for routed batches --
+        hands the sliced triple to the owning request's ``route``.  Only
+        this thread ever touches the sink or ``emitted_cliques`` /
+        ``overflowed_tiles``, so FIFO submission == deterministic sink
+        order with no further synchronization.
 
-        Resilience: injected harvest faults are absorbed in place; a real
-        fetch failure (the triple was lost after launch) re-lists the
-        same batch synchronously in its FIFO slot, demoting down the
-        ladder to the kernel-order host recursion if needed -- so the
-        decoded rows never change."""
+        Resilience: injected harvest faults are absorbed in place; lost
+        decoded rows fall back to the triple; a real fetch failure of the
+        triple (lost after launch) re-lists the same batch synchronously
+        in its FIFO slot, demoting down the ladder to the kernel-order
+        host recursion if needed -- so the decoded rows never change."""
         from ..core import listing
 
         t0 = time.perf_counter()
@@ -1023,23 +1063,35 @@ class ListDispatcher:
         # ratchet/decode -- padding rows count 0 and never overflow
         relaunched = False
         fetched = 0  # device bytes copied to the host for this batch
+        rows = None  # the device decode's fetched rows
         with trace.span("device/wait", batch=batch.index):
             fault_retry.consume("device.harvest", on_retry=self._note_retry)
-            try:
-                bufs, cnt, ovf = (np.asarray(x)[: batch.B] for x in out)
-                fetched += engine_jax.fetched_bytes(out)
-            except fault_retry.RECOVERABLE as exc:
-                self._note_retry(1, exc)
-                relaunched = True
-                cap = min(self._cap_ratchet.get(batch.T, SPECULATIVE_CAP0),
-                          self.max_capacity)
-                out2 = self._relaunch_sync(batch, cap)
+            if dev is not None:
                 try:
-                    bufs, cnt, ovf = (np.asarray(x)[: batch.B] for x in out2)
-                    fetched += engine_jax.fetched_bytes(out2)
-                except fault_retry.RECOVERABLE:
-                    self._note_demotion(self.backend, None)
-                    bufs, cnt, ovf = listing.host_list_triple(batch, self.l)
+                    rows = listing.fetch_decoded(dev)
+                    bufs, cnt, ovf = None, dev.counts, dev.overflow
+                    fetched += engine_jax.fetched_bytes((dev.rows, dev.total))
+                except fault_retry.RECOVERABLE as exc:
+                    # decoded rows lost: fetch and decode the triple instead
+                    self._note_retry(1, exc)
+            if rows is None:
+                try:
+                    bufs, cnt, ovf = (np.asarray(x)[: batch.B] for x in out)
+                    fetched += engine_jax.fetched_bytes(out)
+                except fault_retry.RECOVERABLE as exc:
+                    self._note_retry(1, exc)
+                    relaunched = True
+                    cap = min(self._cap_ratchet.get(batch.T, SPECULATIVE_CAP0),
+                              self.max_capacity)
+                    out2 = self._relaunch_sync(batch, cap)
+                    try:
+                        bufs, cnt, ovf = (np.asarray(x)[: batch.B]
+                                          for x in out2)
+                        fetched += engine_jax.fetched_bytes(out2)
+                    except fault_retry.RECOVERABLE:
+                        self._note_demotion(self.backend, None)
+                        bufs, cnt, ovf = listing.host_list_triple(batch,
+                                                                  self.l)
         if self.capacity == "speculative" or relaunched:
             # the kernel reported true counts, so a too-small guess is
             # retried once on the device at the exact rounded size --
@@ -1080,13 +1132,15 @@ class ListDispatcher:
                 emitted = int(route(batch, bufs, cnt, ovf))
             else:
                 arr = listing.decode_batch(
-                    batch, bufs, cnt, ovf, self.l, self.stats, et_t=self.et_t
-                )
+                    batch, bufs, cnt, ovf, self.l, self.stats, et_t=self.et_t,
+                    device_rows=rows)
                 fault_retry.consume("sink.write", on_retry=self._note_retry)
                 emitted = self.sink.emit(arr)
         t2 = time.perf_counter()
         with self._acct_lock:
             self.stats.emitted_cliques += emitted
+            if rows is not None:
+                self.stats.device_decoded_rows += dev.expected
             self.stats.d2h_bytes += fetched
             if self.stage_times is not None:
                 st = self.stage_times
@@ -1108,11 +1162,12 @@ class ListDispatcher:
     def _harvest_one(self) -> None:
         if not self._inflight:
             self._promote(block=True)
-        _, batch, acand, out, route = self._inflight.popleft()
+        _, batch, acand, out, route, dev = self._inflight.popleft()
         # decode + emit run on the decode worker, overlapping device
         # execution AND this thread's submit/promote work
         self._decoding.append(
-            self._decode_ex.submit(self._decode_job, batch, acand, out, route)
+            self._decode_ex.submit(self._decode_job, batch, acand, out, route,
+                                   dev)
         )
         # promote any counts that landed meanwhile, then bound the decode
         # backlog (it holds references to device buffers)

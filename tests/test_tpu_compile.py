@@ -3,7 +3,8 @@
 The TPU compiler ships with the installed jaxlib, and it compiles for a
 topology that is described rather than attached, so these tests need no
 chip.  They compile the jitted steps a one-chip run executes -- the
-``lax`` count step of both dispatchers and the ``lax`` list step -- at a
+``lax`` count step of both dispatchers, the ``lax`` list step and the
+listing's device decode -- at a
 batch of 256 tiles, every tile width of the pipeline's bins, and l = 3, 4
 (k = 5, 6), and they pin the backend choice that makes ``auto`` land on
 them.  A compile that passes is not a chip run: results and times come
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import listing
 from repro.kernels import ops
 from repro.runtime import dispatch
 
@@ -96,6 +98,23 @@ def test_lax_list_step_compiles_for_v5e(one_chip, no_compile_cache, T, l):
     mem = compiled.memory_analysis()
     # the (B, capacity, l) int32 emit buffer plus the two (B,) vectors
     assert mem.output_size_in_bytes >= BATCH * LIST_CAPACITY * l * 4
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("T", WIDTHS)
+def test_list_decode_step_compiles_for_v5e(one_chip, no_compile_cache, T):
+    # the device decode a sized listing batch runs after its list kernel
+    l, cap = 2, 128
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+              for shape, dtype in (((BATCH, cap, l), jnp.int32),
+                                   ((BATCH,), jnp.uint32),
+                                   ((BATCH,), jnp.uint32),
+                                   ((BATCH, T), jnp.int32),
+                                   ((BATCH, 2), jnp.int32))]
+    compiled = listing._decode_step(l).lower(*shapes).compile()
+    # the (k, B*cap + cap) int32 rows plus the row total
+    assert compiled.memory_analysis().output_size_in_bytes >= \
+        (l + 2) * (BATCH + 1) * cap * 4
     assert "tpu_custom_call" not in compiled.as_text()
 
 
