@@ -41,6 +41,9 @@ class Stats:
     # ordinal -> tiles counted there / MXU-equivalent flops staged there
     device_tiles: Dict[int, int] = dataclasses.field(default_factory=dict)
     device_flops: Dict[int, int] = dataclasses.field(default_factory=dict)
+    # device ordinal (shard index on the mesh path) -> the scheduler cost
+    # model's cost (clique_scheduler.tile_costs) of the tiles placed there
+    device_cost: Dict[int, float] = dataclasses.field(default_factory=dict)
     # wall seconds the host spent NOT blocked while device work was in
     # flight -- an upper bound on the device time hidden by double-buffered
     # staging (the device may finish before the host returns for it);
@@ -131,6 +134,7 @@ class Stats:
         "spill_sizes": "list",
         "device_tiles": "dict",
         "device_flops": "dict",
+        "device_cost": "dict",
         "device_bytes": "dict",
         "staging_overlap_s": "sum",
         "emitted_cliques": "sum",

@@ -88,20 +88,22 @@ def batch_bytes(n_tiles: int, T: int) -> int:
     return int(n_tiles) * (int(T) * W + W) * 4
 
 
-def _account_devices(stats: Stats, per_device_tiles, T: int) -> None:
-    """Fold one batch's per-device tile counts into ``stats``.
+def _account_devices(stats: Stats, per_device_tiles, per_device_cost,
+                     T: int) -> None:
+    """Fold one batch's per-device tile counts and placed cost into ``stats``.
 
     The single accounting path shared by both dispatchers: builds a delta
     :class:`Stats` carrying only the per-device maps and folds it in via
     ``Stats.merge`` (the one merge routine -- see ``Stats._MERGE_KINDS``).
     """
     delta = Stats()
-    for d, c in enumerate(per_device_tiles):
+    for d, (c, cost) in enumerate(zip(per_device_tiles, per_device_cost)):
         if not c:
             continue
         delta.device_tiles[d] = int(c)
         delta.device_flops[d] = batch_flops(int(c), T)
         delta.device_bytes[d] = batch_bytes(int(c), T)
+        delta.device_cost[d] = float(cost)
     stats.merge(delta)
 
 
@@ -365,9 +367,6 @@ class Dispatcher:
         """Number of devices this dispatcher places batches on."""
         return len(self.devices)
 
-    def _account(self, per_device_tiles: np.ndarray, T: int) -> None:
-        _account_devices(self.stats, per_device_tiles, T)
-
     def _note_retry(self, attempt: int, exc: BaseException) -> None:
         """Per-batch attempt accounting hook (``retry.call`` on_retry)."""
         self.stats.retries += 1
@@ -480,11 +479,14 @@ class Dispatcher:
         (:meth:`_launch`); the batch keeps its FIFO position either way.
         """
         t_stage = time.perf_counter()
+        if self.mesh is not None:
+            d = -1
+        else:
+            d = int(np.argmin(self._loads)) if device is None else int(device)
         with trace.span("device/stage", batch=batch.index, B=batch.B,
-                        T=batch.T):
+                        T=batch.T, device=d):
+            costs = tile_costs(batch.sizes, batch.nedges, self.l)
             if self.mesh is not None:
-                d = -1
-
                 def launch_mesh():
                     inject.fire("device.stage")
                     A = _pad_rows(batch.A, self._n_shards)
@@ -504,23 +506,25 @@ class Dispatcher:
                     self.stats.demotions += 1
                     padded, out = batch.B, self._host_partials(batch)
                 shard_rows = max(1, padded // self._n_shards)
-                per_dev = np.bincount(
-                    np.minimum(np.arange(batch.B) // shard_rows, self._n_shards - 1),
-                    minlength=self._n_shards,
-                )
+                shard = np.minimum(np.arange(batch.B) // shard_rows,
+                                   self._n_shards - 1)
+                per_dev = np.bincount(shard, minlength=self._n_shards)
+                per_cost = np.bincount(shard, weights=costs,
+                                       minlength=self._n_shards)
             else:
-                d = int(np.argmin(self._loads)) if device is None else int(device)
-                cost = float(tile_costs(batch.sizes, batch.nedges, self.l).sum())
+                cost = float(costs.sum())
                 self._loads[d] += cost
                 out = self._launch(batch, d)
                 per_dev = np.zeros(self.n_devices, dtype=np.int64)
                 per_dev[d] = batch.B
+                per_cost = np.zeros(self.n_devices)
+                per_cost[d] = cost
         if self.stage_times is not None:
             st = self.stage_times
             st["stage"] = st.get("stage", 0.0) + time.perf_counter() - t_stage
         self.placements.append(d)
         self.tiles += batch.B
-        self._account(per_dev, batch.T)
+        _account_devices(self.stats, per_dev, per_cost, batch.T)
         if not self._inflight:
             # in-flight window (re)opens now; overlap accrues from here
             self._overlap_mark = time.perf_counter()
@@ -899,17 +903,19 @@ class ListDispatcher:
             raise ValueError("emit mode requires a CliqueSink (or per-"
                              "batch route callbacks)")
         t_stage = time.perf_counter()
+        d = int(np.argmin(self._loads)) if device is None else int(device)
         with trace.span("device/stage", batch=batch.index, B=batch.B,
-                        T=batch.T):
-            d = int(np.argmin(self._loads)) if device is None else int(device)
+                        T=batch.T, device=d):
             cost = float(tile_costs(batch.sizes, batch.nedges, self.l).sum())
             self._loads[d] += cost
             self.placements.append(d)
             self.tiles += batch.B
             per_dev = np.zeros(self.n_devices, dtype=np.int64)
             per_dev[d] = batch.B
+            per_cost = np.zeros(self.n_devices)
+            per_cost[d] = cost
             with self._acct_lock:
-                _account_devices(self.stats, per_dev, batch.T)
+                _account_devices(self.stats, per_dev, per_cost, batch.T)
             sized = self.capacity is None or self.capacity == "sized"
             # sized, un-routed batches decode on the device (_promote)
             on_device = sized and route is None and \
