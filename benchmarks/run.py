@@ -221,7 +221,7 @@ def bench_pipeline_stages():
     derive the end-to-end speedup of swapping front-ends under the same
     device compute.
     """
-    from repro.core import engine_jax, pipeline
+    from repro.core import engine_jax, packing, pipeline
     from repro.core import tiles as tiles_mod
 
     g = graph_suite()["rmat12"]
@@ -245,8 +245,9 @@ def bench_pipeline_stages():
     emit("pipeline/rmat12/k5/frontend_vectorized", t_vec,
          f"tiles={n_vec};extract_speedup={t_ref / max(t_vec, 1e-9):.2f}")
     # parallel pack producer: same byte-identical stream, wall clock of
-    # draining it with a free consumer (packing overlaps across workers)
-    workers = pipeline.default_pack_workers()
+    # draining it with a free consumer (packing overlaps across worker
+    # processes)
+    workers = packing.default_proc_workers()
     _, t_par = timed(
         lambda: [b for b in pipeline.stream_batches(
             g, k, order="hybrid", pack_workers=None)], repeat=2)

@@ -252,8 +252,8 @@ def count(g: Graph, k: int, order: str = "hybrid", et_t: int = 3,
     ``stats.plan_cache_hit`` / ``stats.plan_build_s`` report which path
     ran, and ``plan_cache_dir`` adds an on-disk plan store shared across
     processes.  Packing runs on a parallel producer (``pack_workers``
-    threads, default auto; ``0`` forces the serial packer) that keeps up
-    to ``prefetch`` packed batches ahead of device dispatch.
+    worker processes, default auto; ``0`` forces the serial packer) that
+    keeps up to ``prefetch`` packed batches ahead of device dispatch.
 
     Oversize tiles are counted on the host (``stats.spilled_tiles`` /
     ``stats.spill_sizes``).  ``stage_times`` (optional dict) accumulates

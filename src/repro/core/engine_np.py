@@ -81,6 +81,10 @@ class Stats:
     frontend_s: float = 0.0
     pack_queue_occupancy: float = 0.0
     pack_queue_peak: int = 0
+    # batches the stream packed, and of those the ones packed in a worker
+    # process (repro.core.packing.PackPool) rather than in this interpreter
+    packed_batches: int = 0
+    pack_proc_batches: int = 0
     # plan cache (repro.core.pipeline.cached_plan): True when the query's
     # preprocessing came from the keyed in-process/on-disk cache (the
     # O(delta*m) decomposition was skipped); plan_build_s is the cold-path
@@ -149,6 +153,8 @@ class Stats:
         "frontend_s": "sum",
         "pack_queue_occupancy": "mean",
         "pack_queue_peak": "max",
+        "packed_batches": "sum",
+        "pack_proc_batches": "sum",
         "plan_cache_hit": "or",
         "plan_build_s": "sum",
         "plan_repairs": "sum",

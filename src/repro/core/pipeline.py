@@ -44,7 +44,7 @@ from typing import Deque, Dict, Iterator, List, Optional, Sequence, Tuple, \
 
 import numpy as np
 
-from .bitops import pack_bits as _pack_bits
+from . import packing
 from .graph import Graph, greedy_coloring, color_vertex_order, ragged_expand
 from .tiles import Tile
 from .truss import TrussDecomposition, truss_decomposition
@@ -54,24 +54,6 @@ from ..resilience import retry as fault_retry
 
 #: power-of-two tile-size bins; tiles wider than the last bin spill to host
 BINS = (32, 64, 128, 256)
-
-
-def _edge_lookup(ekeys: np.ndarray, m: int, n: int, lo: np.ndarray,
-                 hi: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Membership probe for canonical pairs (lo < hi) against the sorted
-    edge keys ``u * n + v``.
-
-    Returns (hit mask, position in the sorted key array) -- position is
-    only meaningful where ``hit``; callers needing the edge id (e.g. for a
-    pi_tau rank lookup) index with it.  This is the single home of the
-    searchsorted/clip/equality idiom; keep the key encoding in sync with
-    :meth:`repro.core.graph.Graph.edge_keys`.
-    """
-    keys = lo * np.int64(n) + hi
-    p = np.searchsorted(ekeys, keys)
-    p = np.clip(p, 0, max(m - 1, 0))
-    hit = (ekeys[p] == keys) if m else np.zeros(0, dtype=bool)
-    return hit, p
 
 
 def _group_offsets(E: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -167,7 +149,7 @@ def _build_truss_table(g: Graph, td: TrussDecomposition,
         rank_aw = r_e[g.edge_ids(a[owner], w)]
     keep = (rank_aw > r_e[own_e]) & (w != b[owner])
     own_e, w, bb = own_e[keep], w[keep], b[owner][keep]
-    hit, p = _edge_lookup(ek, m, g.n, np.minimum(bb, w), np.maximum(bb, w))
+    hit, p = packing.edge_lookup(ek, m, g.n, np.minimum(bb, w), np.maximum(bb, w))
     hit &= r_e[p] > r_e[own_e]
     E, W = own_e[hit], w[hit]
     # canonical order: reverse pi_tau over tiles, ascending vertex id inside
@@ -205,7 +187,7 @@ def _build_color_table(g: Graph, colors: np.ndarray) -> TileTable:
     keep = (vid[w] > vid[vhi][owner]) & (w != b[owner])
     owner, w = owner[keep], w[keep]
     bb = b[owner]
-    hit, _ = _edge_lookup(ek, m, g.n, np.minimum(bb, w), np.maximum(bb, w))
+    hit, _ = packing.edge_lookup(ek, m, g.n, np.minimum(bb, w), np.maximum(bb, w))
     E, W = owner[hit], w[hit]
     # canonical order: edge id ascending, members by color-DAG position
     order = np.lexsort((vid[W], E))
@@ -519,8 +501,23 @@ def cached_plan(g: Graph, order: str = "hybrid", *,
 # vectorized chunk packing
 # ---------------------------------------------------------------------------
 
-# pairwise-expansion budget per internal slice (caps peak index memory)
-_PAIR_BUDGET = 4_000_000
+def _gather(table: TileTable, ids: np.ndarray, T: int):
+    """A chunk's self-contained packing input: members ``V`` (B, T) padded
+    with zeros, sizes ``sz`` and, for the truss family, the tiles'
+    thresholds (None for the color family)."""
+    ids = np.asarray(ids, dtype=np.int64)
+    sz = (table.offsets[ids + 1] - table.offsets[ids]).astype(np.int64)
+    owner, pos = ragged_expand(sz)
+    V = np.zeros((ids.size, T), dtype=np.int64)
+    V[owner, pos] = table.verts[table.offsets[ids][owner] + pos]
+    thresh = table.thresh[ids] if table.family == "truss" else None
+    return V, sz, thresh
+
+
+def _edge_index(g: Graph, table: TileTable) -> packing.EdgeIndex:
+    return packing.EdgeIndex(
+        table.ekeys, table.erank if table.family == "truss" else None,
+        g.n, g.m)
 
 
 def _chunk_dense(g: Graph, table: TileTable, ids: np.ndarray, T: int):
@@ -530,101 +527,10 @@ def _chunk_dense(g: Graph, table: TileTable, ids: np.ndarray, T: int):
     pairs) with ``pairs = (tile, i, j, pair_rank)`` for i<j adjacent pairs
     (pair_rank is the pi_tau rank of the pair edge for the truss family).
     """
-    ids = np.asarray(ids, dtype=np.int64)
-    B = ids.size
-    sz = (table.offsets[ids + 1] - table.offsets[ids]).astype(np.int64)
-    owner, pos = ragged_expand(sz)
-    V = np.zeros((B, T), dtype=np.int64)
-    V[owner, pos] = table.verts[table.offsets[ids][owner] + pos]
-    D = np.zeros((B, T, T), dtype=bool)
-    po_l: List[np.ndarray] = []
-    pi_l: List[np.ndarray] = []
-    pj_l: List[np.ndarray] = []
-    pr_l: List[np.ndarray] = []
-    # slice the chunk so the i x j pair expansion stays within budget
-    start = 0
-    quad = sz.astype(np.int64) ** 2
-    cum = np.cumsum(quad)
-    while start < B:
-        stop = int(np.searchsorted(
-            cum, (cum[start - 1] if start else 0) + _PAIR_BUDGET) + 1)
-        stop = max(start + 1, min(stop, B))
-        sl = slice(start, stop)
-        so = sz[sl]
-        powner, ppos = ragged_expand(so * so)
-        s_rep = so[powner]
-        i = ppos // s_rep
-        j = ppos % s_rep
-        keep = i < j
-        powner, i, j = powner[keep], i[keep], j[keep]
-        powner_g = powner + start
-        gu = V[powner_g, i]
-        gv = V[powner_g, j]
-        hit, p = _edge_lookup(table.ekeys, g.m, g.n,
-                              np.minimum(gu, gv), np.maximum(gu, gv))
-        if table.family == "truss":
-            hit &= table.erank[p] > table.thresh[ids[powner_g]]
-        powner_g, i, j, p = powner_g[hit], i[hit], j[hit], p[hit]
-        D[powner_g, i, j] = True
-        D[powner_g, j, i] = True
-        po_l.append(powner_g)
-        pi_l.append(i)
-        pj_l.append(j)
-        if table.family == "truss":
-            pr_l.append(table.erank[p])
-        start = stop
-    po = np.concatenate(po_l) if po_l else np.zeros(0, np.int64)
-    pi = np.concatenate(pi_l) if pi_l else np.zeros(0, np.int64)
-    pj = np.concatenate(pj_l) if pj_l else np.zeros(0, np.int64)
-    pr = (np.concatenate(pr_l) if pr_l else np.zeros(0, np.int64)) \
-        if table.family == "truss" else None
-    nedges = np.bincount(po, minlength=B).astype(np.int64)
-    return D, V, sz, nedges, (po, pi, pj, pr)
-
-
-def _greedy_color_chunk(D: np.ndarray, sz: np.ndarray
-                        ) -> Tuple[np.ndarray, np.ndarray]:
-    """Vectorized-across-tiles greedy coloring, replicating ``_local_color``.
-
-    Processing order per tile: degree descending, local id descending (the
-    reference's ``sorted(..., reverse=True)`` tie-break); color = smallest
-    positive value unused by any tile-neighbor.  Returns (colors (B,T) with
-    0 on padding, perm (B,T) = relabel order: color desc, id asc, padding
-    last).
-    """
-    B, T, _ = D.shape
-    ids = np.broadcast_to(np.arange(T, dtype=np.int64), (B, T))
-    deg = D.sum(-1).astype(np.int64)
-    real = ids < sz[:, None]
-    degk = np.where(real, deg, -1)
-    order = np.lexsort((-ids, -degk), axis=1)
-    colors = np.zeros((B, T), dtype=np.int64)
-    for t in range(int(sz.max(initial=0))):
-        # step t touches only tiles with a t-th vertex; indexing the active
-        # subset keeps per-step work O(#active * T), not O(B * T) -- the
-        # dominant win on mixed-size bins (bench_pipeline_stages)
-        act = np.nonzero(t < sz)[0]
-        v = order[act, t]
-        nb = D[act, v]                                    # (A, T)
-        ncol = np.where(nb, colors[act], 0)
-        present = np.zeros((act.size, T + 2), dtype=bool)
-        present[np.arange(act.size)[:, None], ncol] = True
-        mex = np.argmin(present[:, 1:], axis=1) + 1       # first free >= 1
-        colors[act, v] = mex
-    perm = np.lexsort((ids, -colors), axis=1)
-    return colors, perm
-
-
-def _relabel_chunk(D, V, colors, perm):
-    # one flat gather for the (B, T, T) permute (measurably faster than
-    # both a chained take_along_axis and the triple-broadcast fancy index)
-    B, T = V.shape
-    idx = (perm[:, :, None] * T + perm[:, None, :]).reshape(B, T * T)
-    D2 = np.take_along_axis(D.reshape(B, T * T), idx, axis=1) \
-        .reshape(B, T, T)
-    V2 = np.take_along_axis(V, perm, axis=1)
-    C2 = np.take_along_axis(colors, perm, axis=1)
-    return D2, V2, C2
+    V, sz, thresh = _gather(table, ids, T)
+    D, nedges, pairs = packing.dense_pairs(V, sz, thresh,
+                                           _edge_index(g, table))
+    return D, V, sz, nedges, pairs
 
 
 @dataclasses.dataclass
@@ -652,20 +558,20 @@ class TileBatch:
         return int(self.A.shape[0])
 
 
-def _pack_batch(g: Graph, table: TileTable, ids: np.ndarray, T: int,
-                mode: str, index: int = -1) -> TileBatch:
-    # pure function of (table, ids): an injected pack fault is absorbed
-    # by in-place retry before any work happens, so results never change
-    fault_retry.consume("pack")
-    D, V, sz, nedges, _ = _chunk_dense(g, table, ids, T)
-    if mode == "hybrid":
-        colors, perm = _greedy_color_chunk(D, sz)
-        D, V, _ = _relabel_chunk(D, V, colors, perm)
-    A = _pack_bits(D)
-    cand = _pack_bits(np.arange(T)[None, :] < sz[:, None])
+def _tile_batch(T: int, table: TileTable, ids: np.ndarray, sz: np.ndarray,
+                packed: Tuple, index: int) -> TileBatch:
+    A, cand, V, nedges = packed
     return TileBatch(T, A, cand, sz.astype(np.int32),
                      nedges.astype(np.int32), table.anchors[ids].copy(), V,
                      index)
+
+
+def _pack_batch(g: Graph, table: TileTable, ids: np.ndarray, T: int,
+                mode: str, index: int = -1) -> TileBatch:
+    V, sz, thresh = _gather(table, ids, T)
+    packed = packing.pack_dense(V, sz, thresh, _edge_index(g, table),
+                                mode == "hybrid")
+    return _tile_batch(T, table, ids, sz, packed, index)
 
 
 def _tiles_from_ids(g: Graph, table: TileTable, ids: np.ndarray,
@@ -680,8 +586,8 @@ def _tiles_from_ids(g: Graph, table: TileTable, ids: np.ndarray,
         D, V, _, nedges, (po, pi, pj, pr) = _chunk_dense(g, table, sub, T)
         colors_out: Optional[np.ndarray] = None
         if mode == "hybrid":
-            colors, perm = _greedy_color_chunk(D, sz)
-            D, V, colors_out = _relabel_chunk(D, V, colors, perm)
+            colors, perm = packing.greedy_color(D, sz)
+            D, V, colors_out = packing.relabel(D, V, colors, perm)
         elif mode == "color":
             mowner, mpos = ragged_expand(sz)
             colors_out = np.zeros((sub.size, T), dtype=np.int64)
@@ -733,15 +639,6 @@ def iter_tiles(source: Union[Graph, PipelinePlan], k: int,
     yield from _tiles_from_ids(plan.g, table, ids, mode)
 
 
-def default_pack_workers() -> int:
-    """Auto worker count for the parallel pack producer: a small pool,
-    leaving one core for the consumer/device side (packing is numpy-bound
-    and releases the GIL, but past a few threads the front-end saturates
-    host memory bandwidth -- and on CPU-device hosts the packers share
-    cores with the kernels themselves)."""
-    return max(1, min(4, (os.cpu_count() or 2) - 1))
-
-
 def stream_batches(source: Union[Graph, PipelinePlan], k: int,
                    order: str = "hybrid", use_rule2: bool = True,
                    batch_size: Optional[int] = None,
@@ -763,17 +660,20 @@ def stream_batches(source: Union[Graph, PipelinePlan], k: int,
     ``batch`` id of its spans here and in the dispatchers.
 
     ``pack_workers`` turns the serial packer into a producer/consumer
-    pipeline: a thread pool packs up to ``prefetch`` chunks ahead of the
-    consumer (default ``2 * workers``), so host packing of batch i+N
-    overlaps whatever the consumer does with batch i (device dispatch, in
-    the engines).  ``0`` = pack inline (the serial reference behavior);
-    ``None`` = :func:`default_pack_workers`.  The yielded sequence is
-    **identical** in content and order either way -- work items are
-    submitted and harvested strictly FIFO -- and peak host memory grows
-    only by the prefetch window.  With ``stats`` given (a
-    :class:`~repro.core.engine_np.Stats`), ``pack_workers``,
-    ``frontend_s`` (extract + pack seconds; worker CPU-seconds when
-    parallel), and the prefetch-queue occupancy fields are recorded.
+    pipeline: that many worker processes of the interpreter's
+    :class:`~repro.core.packing.PackPool` (which outlives the stream) pack
+    up to ``prefetch`` chunks ahead of the consumer (default ``2 *
+    workers``), so host packing of batch i+N runs outside this interpreter
+    while the consumer does whatever it does with batch i (device
+    dispatch, in the engines).  ``0`` = pack inline (the serial reference
+    behavior); ``None`` = :func:`~repro.core.packing.default_proc_workers`.
+    The yielded sequence is **identical** in content and order every way
+    -- work items are submitted and harvested strictly FIFO -- and peak
+    host memory grows only by the prefetch window.  With ``stats`` given
+    (a :class:`~repro.core.engine_np.Stats`), ``pack_workers``,
+    ``frontend_s`` (extract + pack seconds; gather plus worker seconds
+    when parallel), ``packed_batches``, ``pack_proc_batches`` and the
+    prefetch-queue occupancy fields are recorded.
     """
     if order not in ("truss", "hybrid", "color"):
         raise ValueError(f"unknown edge-tile mode: {order}")
@@ -815,7 +715,7 @@ def stream_batches(source: Union[Graph, PipelinePlan], k: int,
         sel = ids[binidx == bi]
         for c0 in range(0, sel.size, batch_size):
             work.append((T, sel[c0:c0 + batch_size]))
-    workers = default_pack_workers() if pack_workers is None \
+    workers = packing.default_proc_workers() if pack_workers is None \
         else max(0, int(pack_workers))
     serial = workers == 0 or len(work) <= 1
     if stats is not None:
@@ -823,30 +723,50 @@ def stream_batches(source: Union[Graph, PipelinePlan], k: int,
         stats.pack_workers = 0 if serial else workers
     if serial:
         for i, (T, chunk) in enumerate(work):
+            fault_retry.consume("pack")  # pure stage: retry-in-place
             t1 = time.perf_counter()
             with trace.span("pack", batch=i, T=T, tiles=len(chunk)):
                 batch = _pack_batch(plan.g, table, chunk, T, order, i)
             bill_pack(time.perf_counter() - t1)
+            if stats is not None:
+                stats.packed_batches += 1
             yield batch
         return
 
+    pool = packing.pool(workers)
+    index = _edge_index(plan.g, table)
+
     def pack_job(i: int, T: int, chunk: np.ndarray
                  ) -> Tuple[TileBatch, float]:
+        # on a driving thread, which gathers a self-contained work item
+        # (the worker holds only the edge index) and waits on a worker
         t1 = time.perf_counter()
         with trace.span("pack", batch=i, T=T, tiles=len(chunk)):
-            batch = _pack_batch(plan.g, table, chunk, T, order, i)
-        return batch, time.perf_counter() - t1
+            V, sz, thresh = _gather(table, chunk, T)
+            gather_s = time.perf_counter() - t1
+            *packed, worker_s = pool.pack(index, V, sz, thresh,
+                                          order == "hybrid")
+        return _tile_batch(T, table, chunk, sz, packed, i), \
+            gather_s + worker_s
+
+    ex = concurrent.futures.ThreadPoolExecutor(
+        max_workers=workers, thread_name_prefix="pack")
+
+    def submit(i: int, T: int, chunk: np.ndarray):
+        # the fault schedule's call counter stays in this thread, so a
+        # plan fires on the same calls as the inline path's
+        fault_retry.consume("pack")
+        return i, ex.submit(pack_job, i, T, chunk)
 
     depth = max(2, 2 * workers) if prefetch is None else max(1, int(prefetch))
     occ_sum, occ_n, occ_peak = 0.0, 0, 0
     if timings is not None:
         timings.setdefault("pack_wait", 0.0)
-    ex = concurrent.futures.ThreadPoolExecutor(max_workers=workers)
+    futs: Deque = collections.deque()
     try:
         it = enumerate(work)
-        futs: Deque = collections.deque(
-            (i, ex.submit(pack_job, i, T, chunk))
-            for i, (T, chunk) in itertools.islice(it, depth))
+        futs.extend(submit(i, T, chunk)
+                    for i, (T, chunk) in itertools.islice(it, depth))
         while futs:
             occ_peak = max(occ_peak, len(futs))
             occ_sum += len(futs) / depth
@@ -862,11 +782,14 @@ def stream_batches(source: Union[Graph, PipelinePlan], k: int,
                     timings["pack_wait"] += time.perf_counter() - t1
             nxt = next(it, None)
             if nxt is not None:
-                i, (T, chunk) = nxt
-                futs.append((i, ex.submit(pack_job, i, T, chunk)))
+                futs.append(submit(nxt[0], *nxt[1]))
             bill_pack(dt)
+            if stats is not None:
+                stats.packed_batches += 1
+                stats.pack_proc_batches += 1
             yield batch
     finally:
+        # the pool outlives the stream; its queued work does not
         ex.shutdown(wait=False, cancel_futures=True)
         if stats is not None and occ_n:
             stats.pack_queue_occupancy = occ_sum / occ_n

@@ -27,7 +27,7 @@ from typing import List, Optional
 
 import numpy as np
 
-from ..core import pipeline
+from ..core import packing, pipeline
 from ..core.graph import Graph, ragged_expand
 from ..core.truss import (TrussDecomposition, edge_subset_supports)
 from ..obs import trace
@@ -40,7 +40,7 @@ from ..obs import trace
 CHURN_THRESHOLD = 0.15
 
 # pair-expansion budget for the common-neighborhood scan (caps peak
-# index memory, mirroring pipeline._PAIR_BUDGET)
+# index memory, mirroring packing._PAIR_BUDGET)
 _PAIR_BUDGET = 4_000_000
 
 
@@ -81,7 +81,7 @@ def touched_edge_ids(g: Graph, batch_keys: np.ndarray) -> np.ndarray:
     ek = g.edge_keys()
     parts: List[np.ndarray] = []
     # (1) the batch pairs that are edges of g
-    hit, p = pipeline._edge_lookup(ek, g.m, g.n, bu, bv)
+    hit, p = packing.edge_lookup(ek, g.m, g.n, bu, bv)
     parts.append(p[hit])
     # common neighborhood of each batch pair: expand the smaller
     # endpoint's adjacency, keep vertices adjacent to the other endpoint

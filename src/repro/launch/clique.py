@@ -100,7 +100,7 @@ def main():
     ap.add_argument("--max-out", type=int, default=None,
                     help="with --list: stop after this many cliques")
     ap.add_argument("--pack-workers", type=int, default=None,
-                    help="parallel pack-producer threads (default auto; "
+                    help="pack worker processes (default auto; "
                          "0 = serial inline packing)")
     ap.add_argument("--plan-cache", default=None, metavar="DIR",
                     help="persist the PipelinePlan (truss order + tile "

@@ -9,8 +9,9 @@ Two searches live here:
 * :func:`tune_geometry` -- budgeted coordinate descent over the shape
   knobs the pipeline keys executables on: tile-width rounding policy
   (pow2 bins vs multiples of 32 -- fewer distinct ``(T, W)`` signatures vs
-  tighter packing), ``batch_size``, emit-capacity rounding policy and cap,
-  and the pack-producer's ``pack_workers``/``prefetch``.  The descent
+  tighter packing), ``batch_size``, and emit-capacity rounding policy and
+  cap.  The pack pool's size is not tuned: it comes from the host's cores
+  (:func:`repro.core.packing.default_proc_workers`).  The descent
   starts from the hardcoded defaults and only moves off them on a > 2%
   measured win, so the tuned geometry never loses to the defaults by more
   than measurement noise; the result is persisted as one geometry
@@ -88,6 +89,8 @@ def geometry_from_record(rec: TuningRecord) -> Geometry:
     """Geometry encoded in a record; unknown/missing fields keep defaults."""
     g = Geometry()
     for f in dataclasses.fields(Geometry):
+        if f.name == "pack_workers":  # untuned: a record's value is stale
+            continue
         if f.name in rec.data and rec.data[f.name] is not None:
             setattr(g, f.name, rec.data[f.name])
     return g
@@ -261,7 +264,6 @@ def tune_geometry(mode: str, l: int, *, budget_s: float = 20.0,
     knobs: List[Tuple[str, list]] = [
         ("t_policy", ["mult32"]),
         ("batch_size", [64, 128, 512]),
-        ("pack_workers", [0, 2]),
     ]
     if mode == "list":
         knobs.append(("cap_policy", ["mult64"]))

@@ -1,5 +1,7 @@
 """Unified tile pipeline: parity with the Python reference oracle,
 streaming-batcher invariants, scheduler mapping, oversize spill."""
+import signal
+
 import numpy as np
 import pytest
 
@@ -106,27 +108,195 @@ def test_plan_reuse_skips_preprocessing(rng):
     assert r2.count == ebbkc.count(g, 5).count
 
 
-@pytest.mark.parametrize("mode", ["truss", "color", "hybrid"])
-def test_parallel_producer_matches_serial(mode):
+MODES = ("truss", "color", "hybrid")
+# three workers with a window of four chunks, and one worker with a window
+# of one: the two corners of the FIFO
+PRODUCER_CASES = [pytest.param(3, 4, mode, 5, id=mode) for mode in MODES] \
+    + [pytest.param(3, 4, mode, k, id=f"{mode}-k{k}")
+       for mode in MODES for k in (4, 6)] \
+    + [pytest.param(1, 1, mode, k, id=f"one-worker-{mode}-k{k}")
+       for mode in MODES for k in (4, 5, 6)]
+
+
+@pytest.fixture
+def deadline():
+    """A time limit of this test's own: a hung pack pool fails the test
+    instead of running the suite into its limit."""
+    def expire(signum, frame):
+        raise TimeoutError("the pack pool hung past the test's 90 s limit")
+
+    old = signal.signal(signal.SIGALRM, expire)
+    signal.alarm(90)
+    yield
+    signal.alarm(0)
+    signal.signal(signal.SIGALRM, old)
+
+
+def assert_same_stream(ref, got):
+    """Byte-identical streams: every batch array, dtype, index and spill."""
+    assert len(ref) == len(got)
+    for a, b in zip(ref, got):
+        assert type(a) is type(b)
+        if isinstance(a, pipeline.TileBatch):
+            assert (a.T, a.index) == (b.T, b.index)
+            for f in ("A", "cand", "sizes", "nedges", "anchors", "verts"):
+                x, y = getattr(a, f), getattr(b, f)
+                assert x.dtype == y.dtype and np.array_equal(x, y), f
+        else:
+            assert tiles_equal(a, b)
+
+
+@pytest.mark.parametrize("workers, prefetch, mode, k", PRODUCER_CASES)
+def test_parallel_producer_matches_serial(workers, prefetch, mode, k,
+                                          deadline):
     """pack_workers > 0 yields the byte-identical batch stream (content
-    AND order), spill tiles included, for every ordering."""
+    AND order), spill tiles included, for every ordering, packed in
+    worker processes."""
+    from repro.core.engine_np import Stats
+
     g = rmat_graph(8, 4, seed=7)
     for bins, batch_size in (((32, 64, 128, 256), 16), ((32,), 8)):
-        ref = list(pipeline.stream_batches(g, 5, order=mode, bins=bins,
+        ref = list(pipeline.stream_batches(g, k, order=mode, bins=bins,
                                            batch_size=batch_size))
-        got = list(pipeline.stream_batches(g, 5, order=mode, bins=bins,
+        stats = Stats()
+        got = list(pipeline.stream_batches(g, k, order=mode, bins=bins,
                                            batch_size=batch_size,
-                                           pack_workers=3, prefetch=4))
-        assert len(ref) == len(got)
-        for a, b in zip(ref, got):
-            assert type(a) is type(b)
-            if isinstance(a, pipeline.TileBatch):
-                assert np.array_equal(a.A, b.A)
-                assert np.array_equal(a.cand, b.cand)
-                assert np.array_equal(a.verts, b.verts)
-                assert np.array_equal(a.anchors, b.anchors)
-            else:
-                assert a.anchor == b.anchor and a.rows == b.rows
+                                           pack_workers=workers,
+                                           prefetch=prefetch, stats=stats))
+        assert_same_stream(ref, got)
+        assert stats.packed_batches == sum(
+            isinstance(b, pipeline.TileBatch) for b in got) > 1
+        assert stats.pack_proc_batches == stats.packed_batches
+
+
+def test_pack_faults_fire_alike_on_both_executors(deadline):
+    """An armed pack fault plan is absorbed in place inline and in worker
+    processes: the same stream, the same faults fired and counted."""
+    from repro.obs import metrics as obs_metrics
+    from repro.resilience import inject
+
+    g = rmat_graph(8, 4, seed=7)
+    ref = list(pipeline.stream_batches(g, 5, batch_size=8))
+    counter = obs_metrics.get_registry().counter(
+        "repro_faults_injected_total", site="pack")
+    seen = {}
+    for workers in (0, 2):
+        before = counter.value
+        inject.configure("seed=3;pack=0.4")
+        try:
+            got = list(pipeline.stream_batches(g, 5, batch_size=8,
+                                               pack_workers=workers,
+                                               prefetch=3))
+            seen[workers] = (inject.calls("pack"), inject.fired("pack"),
+                             counter.value - before)
+        finally:
+            inject.configure(None)
+        assert_same_stream(ref, got)
+    assert seen[0] == seen[2]
+    assert seen[2][1] > 0
+
+
+def test_process_pool_lives_with_the_table(deadline):
+    """Two jobs on one plan reuse one pool and ship the edge index once;
+    after an edge batch the same worker processes pack the repaired plan
+    with its new index, and count what a fresh plan counts."""
+    from repro.core import packing
+    from repro.core.engine_np import Stats
+    from repro.core.graph import apply_edge_batch
+    from repro.delta import repair_plan
+
+    g = rmat_graph(8, 4, seed=7)
+    plan = pipeline.build_plan(g, "hybrid")
+
+    def job(p):
+        stats = Stats()
+        items = list(pipeline.stream_batches(p, 5, batch_size=8,
+                                             pack_workers=2, stats=stats))
+        assert stats.pack_proc_batches == stats.packed_batches > 1
+        return items
+
+    first = job(plan)
+    pool = packing.pool(2)
+    pids, sends = pool.pids(), pool.index_sends
+    # a stream abandoned with chunks in flight leaves nothing behind
+    stream = pipeline.stream_batches(plan, 5, batch_size=8, pack_workers=2)
+    next(stream)
+    stream.close()
+    assert_same_stream(first, job(plan))
+    assert packing.pool(2) is pool and pool.pids() == pids
+    assert pool.index_sends == sends  # the second job shipped no index
+
+    g2 = apply_edge_batch(g, insert=[(0, 200), (3, 150)],
+                          delete=[tuple(g.edges[0]), tuple(g.edges[50])])
+    plan2, info = repair_plan(plan, g2, "hybrid", churn_threshold=1.1)
+    assert not info.rebuilt
+    # a repaired table orders its tiles its own way, so its stream is
+    # compared with its own inline stream, and its count with a fresh plan's
+    assert_same_stream(list(pipeline.stream_batches(plan2, 5, batch_size=8)),
+                       job(plan2))
+    assert packing.pool(2) is pool and pool.pids() == pids
+    assert pool.index_sends > sends
+    got = engine_jax.count(g2, 5, plan=plan2, batch_size=8, pack_workers=2)
+    assert got.stats.pack_proc_batches > 0
+    assert got.count == ebbkc.count(g2, 5,
+                                    plan=pipeline.build_plan(g2)).count
+
+
+def test_crashed_pack_worker_raises(deadline):
+    """A pack worker that dies fails the stream; the next stream gets a
+    fresh pool."""
+    import os
+
+    from repro.core import packing
+
+    g = rmat_graph(8, 4, seed=7)
+    ref = list(pipeline.stream_batches(g, 5, batch_size=8))
+    pool = packing.pool(2)
+    os.kill(pool.pids()[0], signal.SIGKILL)
+    with pytest.raises(packing.PackWorkerError):
+        list(pipeline.stream_batches(g, 5, batch_size=8, pack_workers=2,
+                                     prefetch=4))
+    assert pool.broken
+    assert packing.pool(2) is not pool
+    assert_same_stream(ref, list(pipeline.stream_batches(
+        g, 5, batch_size=8, pack_workers=2)))
+
+
+def test_pack_workers_import_no_jax_and_exit_with_the_parent(tmp_path):
+    """A worker boots from repro.core.packing without JAX, and a program
+    that packs in processes leaves none behind when it exits."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.core import packing
+
+    boot = subprocess.run(
+        [sys.executable, "-X", "importtime", "-c", packing.BOOT,
+         packing._SRC], stdin=subprocess.DEVNULL, capture_output=True,
+        timeout=60)
+    assert boot.returncode == 0, boot.stderr.decode()
+    mods = {line.rsplit("|", 1)[-1].strip()
+            for line in boot.stderr.decode().splitlines()
+            if line.startswith("import time:")}
+    assert "repro.core.packing" in mods
+    assert not {m for m in mods if m.split(".")[0] in ("jax", "jaxlib")}
+
+    script = tmp_path / "job.py"
+    script.write_text(
+        "import sys\n"
+        f"sys.path.insert(0, {packing._SRC!r})\n"
+        "from repro.core import packing, pipeline\n"
+        "from repro.data import rmat_graph\n"
+        "g = rmat_graph(8, 4, seed=7)\n"
+        "list(pipeline.stream_batches(g, 5, batch_size=8, pack_workers=2))\n"
+        "print(*packing.pool(2).pids())\n")
+    run = subprocess.run([sys.executable, str(script)], capture_output=True,
+                         timeout=60)
+    assert run.returncode == 0, run.stderr.decode()
+    pids = [int(p) for p in run.stdout.split()]
+    assert len(pids) == 2
+    assert not [p for p in pids if os.path.exists(f"/proc/{p}")]
 
 
 def test_parallel_producer_stats_and_timings():
